@@ -1,7 +1,9 @@
 """TPU-fabric multicast benchmark (fig. 3b adapted): collective bytes and
-op counts for unicast / sw_tree / hw distribution of a 16 MiB buffer
-along an 8-way axis, measured from compiled HLO in a subprocess with
-8 fake devices (the parent process stays single-device)."""
+op counts for unicast / sw_tree / hw distribution of a 4 MiB buffer
+along an 8-way axis, counted from compiled HLO in a subprocess with
+8 fake CPU devices (the parent process stays single-device).  The child
+is pinned to the CPU backend: it counts HLO by design, and must never
+contend with its parent for an accelerator."""
 import json
 import os
 import subprocess
@@ -37,11 +39,15 @@ print("RESULT " + json.dumps(out))
 
 def run() -> list[str]:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=f"{root}/src:{root}")
+    env = dict(os.environ, PYTHONPATH=f"{root}/src:{root}", JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD], env=env, capture_output=True, text=True,
         timeout=900,
     )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"collective-bytes child failed (rc {proc.returncode}):\n"
+            f"{proc.stderr[-3000:]}")
     for line in proc.stdout.splitlines():
         if line.startswith("RESULT "):
             data = json.loads(line[len("RESULT "):])
@@ -57,4 +63,5 @@ def run() -> list[str]:
                     f"ops={d['counts']} speedup_vs_unicast={ratio:.1f}x"
                 )
             return rows
-    return [f"fig3b_tpu_error,0,{proc.stderr[-200:]!r}"]
+    raise RuntimeError(f"collective-bytes child printed no RESULT line:\n"
+                       f"{proc.stdout[-3000:]}")

@@ -38,6 +38,8 @@ import importlib
 import json
 import sys
 
+from repro import runtime
+
 DEFAULT_JSON = "BENCH_kernels.json"
 
 # (module, row-name prefixes it emits, accepts only=, needs subprocess)
@@ -90,6 +92,7 @@ def main() -> None:
     ap.add_argument("--only", default=None, metavar="SUBSTR",
                     help="only measure rows whose name contains SUBSTR")
     args = ap.parse_args()
+    runtime.setup_compile_cache()
 
     rows: list[str] = []
     skipped_subprocess: list[str] = []

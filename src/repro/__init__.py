@@ -1,4 +1,1 @@
 """repro: multicast-crossbar paper reproduction on jax/Pallas."""
-from repro import compat as _compat
-
-_compat.install()

@@ -21,7 +21,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 MODES = ("unicast", "sw_tree", "hw")
@@ -60,8 +59,8 @@ def make_broadcast_fn(mesh, shape, dtype, mode: str):
         masked = jnp.where(i == 0, x, jnp.zeros_like(x))
         return _from_source(masked, mode, axis, n)
 
-    return shard_map(
-        body, mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
     )
 
 
@@ -97,8 +96,8 @@ def make_weight_gather_fn(mesh, shape, dtype, mode: str):
             return acc
         raise ValueError(f"unknown mode: {mode!r} (have {MODES})")
 
-    return shard_map(
-        body, mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
     )
 
 
@@ -114,9 +113,9 @@ def mcast_matmul(x, w, mesh, *, mode: str = "hw"):
         wl = _from_source(masked, mode, axis, n)
         return xs @ wl
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis, None), P()), out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     return f(x, w)
 

@@ -335,6 +335,8 @@ class KernelOp:
                     s for s in self.schedules
                     if s.backend == "reference" and (s.vjp or not needs_vjp)
                 ]
+                if backend == "pallas":
+                    _note_substitution(self.name, problem)
             sched = min(
                 avail, key=lambda s: s.cost(problem) if s.cost else math.inf
             )
@@ -1096,16 +1098,36 @@ register(KernelOp(
 
 @dataclasses.dataclass
 class FallbackStats:
-    """Cumulative counters for :func:`call_with_fallback`."""
+    """Cumulative counters for :func:`call_with_fallback`, plus the
+    auto-dispatch substitutions: calls whose default pallas backend had
+    no available schedule and were resolved to the reference backend
+    instead (counted per resolve, i.e. per kernel site per trace)."""
 
     calls: int = 0  # guarded calls attempted
     fallbacks: int = 0  # calls that completed on the reference retry
     raised: int = 0  # primary raised an exception
     numeric_trips: int = 0  # primary returned non-finite output
+    substitutions: int = 0  # default-pallas resolves that picked reference
     last_error: str | None = None
 
 
 _FALLBACK_STATS = FallbackStats()
+_SUBSTITUTED: set[tuple[str, Problem]] = set()
+
+
+def _note_substitution(op_name: str, problem: Problem) -> None:
+    """Count one reference substitution on the pallas default backend and
+    warn once per (op, problem): on the chip it means XLA, not the
+    kernel, runs that site."""
+    _FALLBACK_STATS.substitutions += 1
+    key = (op_name, problem)
+    if key in _SUBSTITUTED:
+        return
+    _SUBSTITUTED.add(key)
+    warnings.warn(
+        f"repro.kernels: no pallas schedule of {op_name!r} is available for "
+        f"shape {problem.shape} {problem.dtype}; the reference backend runs "
+        f"it instead", RuntimeWarning, stacklevel=4)
 
 
 def fallback_stats() -> FallbackStats:
@@ -1116,6 +1138,7 @@ def fallback_stats() -> FallbackStats:
 def reset_fallback_stats() -> None:
     global _FALLBACK_STATS
     _FALLBACK_STATS = FallbackStats()
+    _SUBSTITUTED.clear()
 
 
 def all_finite(*arrays) -> bool:

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -2.0**30
 
@@ -153,7 +152,7 @@ def paged_attention_decode(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -311,7 +310,7 @@ def paged_attention_prefill(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s_pad, kvh, group, d), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _rglru_body(a_ref, b_ref, o_ref, h_ref, *, bs: int):
@@ -69,7 +68,7 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, bs, bd), lambda bi, di, si: (bi, si, di)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -122,7 +121,7 @@ def rglru_scan_bwd(
             jax.ShapeDtypeStruct((bsz, s, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _ssd_body(xdt_ref, b_ref, c_ref, lcum_ref, o_ref, *rest, q: int):
@@ -111,7 +110,7 @@ def ssd_scan(
         out_specs=out_specs if return_states else y_spec,
         out_shape=out_shape if return_states else out_shape[0],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -259,7 +258,7 @@ def ssd_scan_bwd(
             jax.ShapeDtypeStruct((bsz, h, s, 1), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

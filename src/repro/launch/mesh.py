@@ -11,23 +11,24 @@ pod axis adds the second hierarchy level (2 pods x 256 chips).
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, *, pod: int | None = None):
     """Small mesh for CPU tests (requires >= data*model fake devices)."""
     if pod:
-        return make_mesh(
+        return jax.make_mesh(
             (pod, data, model), ("pod", "data", "model"),
             axis_types=(AxisType.Auto,) * 3,
         )
-    return make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serve_mesh(num_shards: int = 4, *, axis: str = "data"):
@@ -35,4 +36,4 @@ def make_serve_mesh(num_shards: int = 4, *, axis: str = "data"):
     over: ``num_shards`` devices along one named axis.  Requires at
     least ``num_shards`` (fake or real) devices — CI forces them with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=4``."""
-    return make_mesh((num_shards,), (axis,), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((num_shards,), (axis,), axis_types=(AxisType.Auto,))

@@ -39,6 +39,10 @@ mesh-sharded page pool with multicast page-chain broadcast (``--mesh``
 additionally shards the device page arrays over a 1-D mesh — CI forces
 4 CPU devices with ``XLA_FLAGS=--xla_force_host_platform_device_count``).
 
+``main(argv)`` also runs in-process and returns a :class:`ServeRun`
+(the engine, the drained requests and, under ``--server``, the metrics
+snapshot) — ``chip_smoke.py`` drives the chip through it.
+
 CPU demo: PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
     --reduced --requests 6 --max-new 16 [--kv paged]
 Server:  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
@@ -47,6 +51,7 @@ Server:  PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
 
@@ -54,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import kernels
+from repro import kernels, runtime
 from repro.configs import ARCHS, get_config
 from repro.configs.registry import draft_for
 from repro.models import lm
@@ -167,6 +172,17 @@ class Server:
         return done
 
 
+@dataclasses.dataclass
+class ServeRun:
+    """What one ``main`` call served: the engine it built, the drained
+    requests, and (``--server`` loop driver) the validated metrics
+    snapshot."""
+
+    engine: PagedEngine | Server
+    done: list[Request]
+    snapshot: dict | None = None
+
+
 def _print_request_lines(done: list[Request]) -> None:
     # stdout is the parity surface: the async loop and the synchronous
     # oracle must print byte-identical lines (CI diffs them)
@@ -176,7 +192,12 @@ def _print_request_lines(done: list[Request]) -> None:
     print(f"served {len(done)} requests with continuous batching")
 
 
-def main() -> None:
+def _length_range(text: str) -> int | tuple[int, int]:
+    lo, _, hi = text.partition(",")
+    return (int(lo), int(hi)) if hi else int(lo)
+
+
+def main(argv: list[str] | None = None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="qwen1.5-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -190,6 +211,11 @@ def main() -> None:
                     help="--server: mean Poisson arrival rate")
     ap.add_argument("--duration", type=float, default=2.0,
                     help="--server: trace length in seconds")
+    ap.add_argument("--prompt-len", type=_length_range, default=(4, 12),
+                    metavar="N|LO,HI",
+                    help="--server: prompt body length, fixed or drawn "
+                         "uniformly from [LO, HI] (the shared prefix comes "
+                         "on top)")
     ap.add_argument("--shared-frac", type=float, default=0.5,
                     help="--server: fraction of requests opening with the "
                          "--shared-prefix tokens (multicast fan-out mix)")
@@ -222,7 +248,8 @@ def main() -> None:
     # --chaos --seed --num-shards --mesh-axis --mcast-mode --pages-per-shard
     # --trace
     add_serve_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    runtime.setup_compile_cache()
 
     if args.kv is None:
         args.kv = "paged" if args.server else "dense"
@@ -250,7 +277,7 @@ def main() -> None:
     cfg = get_config(args.arch, reduced=args.reduced)
     rec = _arm_trace(serve_cfg)
     try:
-        _drive(args, cfg, serve_cfg)
+        return _drive(args, cfg, serve_cfg)
     finally:
         # trace lands even on a SystemExit from undrained requests —
         # the failing run is exactly the one worth profiling
@@ -258,7 +285,7 @@ def main() -> None:
             _finish_trace(rec, serve_cfg.trace)
 
 
-def _drive(args, cfg, serve_cfg: ServeConfig) -> None:
+def _drive(args, cfg, serve_cfg: ServeConfig) -> ServeRun:
     params = lm.init(cfg, jax.random.PRNGKey(serve_cfg.seed))
     sampler = get_sampler(serve_cfg.sampler)
     if args.kv == "paged":
@@ -285,8 +312,7 @@ def _drive(args, cfg, serve_cfg: ServeConfig) -> None:
     plan = serve_cfg.fault_plan()
 
     if args.server:
-        _run_server(args, cfg, serve_cfg, server, plan)
-        return
+        return _run_server(args, cfg, serve_cfg, server, plan)
 
     rng = np.random.default_rng(serve_cfg.seed)
     prefix = list(rng.integers(0, cfg.vocab, size=args.shared_prefix))
@@ -308,16 +334,17 @@ def _drive(args, cfg, serve_cfg: ServeConfig) -> None:
     _print_request_lines(done)
     if args.kv == "paged":
         print(f"# paged kv stats: {server.stats()}", file=sys.stderr)
+    return ServeRun(server, done)
 
 
 def _run_server(args, cfg, serve_cfg: ServeConfig, engine: PagedEngine,
-                plan: FaultPlan | None) -> None:
+                plan: FaultPlan | None) -> ServeRun:
     """``--server``: one seeded trace, two drivers.  ``loop`` is the
     async ServeLoop (metrics snapshot validated + optionally written);
     ``sync`` is the turn-by-turn oracle.  Identical stdout by design."""
     gen = LoadGen(
         seed=serve_cfg.seed, qps=args.qps, duration=args.duration,
-        vocab=cfg.vocab, max_new=args.max_new,
+        vocab=cfg.vocab, prompt_len=args.prompt_len, max_new=args.max_new,
         shared_prefix_len=args.shared_prefix, shared_frac=args.shared_frac,
     )
     trace = gen.trace()
@@ -335,7 +362,7 @@ def _run_server(args, cfg, serve_cfg: ServeConfig, engine: PagedEngine,
             done = engine.run(reqs)
         _print_request_lines(done)
         print(f"# paged kv stats: {engine.stats()}", file=sys.stderr)
-        return
+        return ServeRun(engine, done)
 
     loop = ServeLoop(engine, config=serve_cfg, metrics=ServeMetrics())
     if plan is not None:
@@ -361,6 +388,7 @@ def _run_server(args, cfg, serve_cfg: ServeConfig, engine: PagedEngine,
                if r.state is not Lifecycle.DRAINED}
         if bad:
             raise SystemExit(f"requests did not drain: {bad}")
+    return ServeRun(engine, drained, snap)
 
 
 def _arm_trace(serve_cfg: ServeConfig):

@@ -24,7 +24,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import kernels
+from repro import kernels, runtime
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCHS, get_config
 from repro.configs.shapes import ShapeCfg
@@ -155,6 +155,7 @@ def main() -> None:
                          "run here (kernel dispatch spans fire at jit trace "
                          "time, so expect one span per compiled program)")
     args = ap.parse_args()
+    runtime.setup_compile_cache()
     if args.trace:
         from repro.obs import export as obs_export
         from repro.obs import trace as obs_trace

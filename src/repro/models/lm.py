@@ -267,7 +267,8 @@ def mask_cache_rows_after(caches, lengths):
     return jax.tree.map(fix, caches, is_leaf=lambda x: isinstance(x, _DENSE_CACHES))
 
 
-def prefill_to_pages(dense_caches, paged_caches, block_table, length):
+def prefill_to_pages(dense_caches, paged_caches, block_table, length, *,
+                     page_axis=None):
     """Scatter a batch-1 dense prefill cache into the page pools.
 
     ``block_table``: (pages,) page ids covering positions
@@ -276,7 +277,12 @@ def prefill_to_pages(dense_caches, paged_caches, block_table, length):
     ``lm.prefill`` as the dense path and then land here, so the page
     bytes are bit-identical to the dense fallback's ring bytes.  (The
     prefix-hit *suffix* path never comes through here — it writes its
-    pages directly via ``decode_step``, one call per prefill chunk.)"""
+    pages directly via ``decode_step``, one call per prefill chunk.)
+    ``page_axis``: as in :func:`decode_step` — ids are global, the pools
+    this device's block; pages another device owns go to its null page."""
+    if page_axis is not None:
+        block = jax.tree.leaves(paged_caches)[0].shape[2]
+        block_table = attn_mod.local_page_ids(block_table, page_axis, block)[0]
     flat_d, _ = jax.tree_util.tree_flatten(
         dense_caches, is_leaf=lambda x: isinstance(x, _DENSE_CACHES)
     )
@@ -328,7 +334,7 @@ def _scatter_dense_into_pages(dense_c, paged_c, table, length):
 
 def _apply_block(params, bd: BlockDef, cfg: ModelConfig, x, *, mode: str,
                  cache=None, index=None, cache_slots=None,
-                 block_table=None, lengths=None):
+                 block_table=None, lengths=None, page_axis=None):
     """Returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     h = _norm(cfg, params["norm1"], x)
@@ -344,6 +350,7 @@ def _apply_block(params, bd: BlockDef, cfg: ModelConfig, x, *, mode: str,
                 m, new_cache = paged_fn(
                     params["attn"], h, cache, cfg.attn, index=index,
                     block_table=block_table, lengths=lengths, window=bd.window,
+                    page_axis=page_axis,
                 )
             else:
                 decode_fn = (
@@ -423,7 +430,7 @@ def _kv_from_full(params, h, cfg: ModelConfig, bd: BlockDef, cache_slots=None):
 
 def _run_stage(params_stage, pattern, cfg: ModelConfig, x, *, mode, caches=None,
                index=None, remat=False, cache_slots=None,
-               block_table=None, lengths=None):
+               block_table=None, lengths=None, page_axis=None):
     def super_block(carry, xs):
         x, aux = carry
         p_sb, cache_sb = xs
@@ -433,7 +440,7 @@ def _run_stage(params_stage, pattern, cfg: ModelConfig, x, *, mode, caches=None,
             x, nc, a = _apply_block(
                 p_sb[f"b{j}"], bd, cfg, x, mode=mode, cache=c, index=index,
                 cache_slots=cache_slots, block_table=block_table,
-                lengths=lengths,
+                lengths=lengths, page_axis=page_axis,
             )
             if nc is not None:
                 new_caches[f"b{j}"] = nc
@@ -565,7 +572,7 @@ def prefill(params, cfg: ModelConfig, tokens, *, frontend_embeds=None,
 
 
 def decode_step(params, cfg: ModelConfig, caches, tokens, index, *,
-                block_table=None, lengths=None):
+                block_table=None, lengths=None, page_axis=None):
     """One decode step (or a few — paged suffix prefills pass s_new > 1).
 
     tokens: (batch, s_new); index: absolute position of the first new
@@ -576,7 +583,10 @@ def decode_step(params, cfg: ModelConfig, caches, tokens, index, *,
     splits a long divergent suffix into fixed-size chunks and calls
     this once per chunk (advancing ``index``/``lengths``), which writes
     the same page bytes as one big call — on TPU each multi-token call
-    runs the paged-attention supertile kernel.
+    runs the paged-attention supertile kernel.  ``page_axis`` names the
+    mesh axis a sharded page pool is split over: the caller runs this
+    inside a ``shard_map`` over it, with each device holding one pool
+    shard's page block (``nn.attention.write_and_attend``).
 
     Returns (logits (batch, s_new, vocab), updated caches)."""
     x = _embed_inputs(params, cfg, tokens)
@@ -585,7 +595,7 @@ def decode_step(params, cfg: ModelConfig, caches, tokens, index, *,
         x, _, stage_cache = _run_stage(
             params[f"stage{i}"], pattern, cfg, x,
             mode="decode", caches=caches[f"stage{i}"], index=index,
-            block_table=block_table, lengths=lengths,
+            block_table=block_table, lengths=lengths, page_axis=page_axis,
         )
         new_caches[f"stage{i}"] = stage_cache
     x = _norm(cfg, params["final_norm"], x)

@@ -140,6 +140,48 @@ def paged_write(pages, values, page_ids, rows):
     return pages.at[:, page_ids, rows].set(values.transpose(2, 0, 1, 3))
 
 
+def local_page_ids(ids, page_axis: str, block: int):
+    """Global page ids -> this device's block of a page pool sharded
+    evenly over the mesh axis ``page_axis`` (call inside a ``shard_map``
+    over it; ``block`` pages per device).  Returns ``(local_ids, mine)``:
+    ids another device owns become the local null page 0."""
+    base = jax.lax.axis_index(page_axis) * block
+    mine = (ids >= base) & (ids < base + block)
+    return jnp.where(mine, ids - base, 0), mine
+
+
+def write_and_attend(q, pages: tuple, new: tuple, block_table, page_ids, rows,
+                     start, lengths, *, softcap=None, page_axis: str | None = None):
+    """Scatter this call's new rows into the pool arrays, then run the
+    ``paged_attention`` op over them.  ``pages`` is the pool's array
+    tuple (K, V and, for int8 pools, the K/V scales, in that order) and
+    ``new`` the matching per-token rows (b, s, kvh, ·); ``page_ids`` /
+    ``rows`` are the write coordinates.  Returns ``(o, pages)``.
+
+    ``page_axis`` names the mesh axis a sharded pool's page axis is
+    split over; the caller runs this inside a ``shard_map`` over it, so
+    ``pages`` are this device's block (one pool shard, led by its null
+    page).  Page ids are translated to the block, the device computes
+    only the rows whose pages it owns (a row's pages all live on one
+    shard, so its first table entry names the owner; other rows get
+    length 0 and write to the null page), and a ``psum`` over the axis
+    assembles the rows.  No page array leaves its device."""
+    if page_axis is not None:
+        block = pages[0].shape[1]
+        block_table, mine = local_page_ids(block_table, page_axis, block)
+        page_ids = local_page_ids(page_ids, page_axis, block)[0]
+        owned = mine[:, 0]
+        lengths = jnp.where(owned, lengths, 0)
+    pages = tuple(paged_write(a, n, page_ids, rows) for a, n in zip(pages, new))
+    k, v, *scales = pages
+    o = kernels.op("paged_attention")(
+        q, k, v, block_table, start, lengths, *scales, softcap=softcap)
+    if page_axis is not None:
+        o = jnp.where(owned[:, None, None, None], o, jnp.zeros_like(o))
+        o = jax.lax.psum(o, page_axis)
+    return o, pages
+
+
 def paged_decode_attention(
     params,
     x,
@@ -150,6 +192,7 @@ def paged_decode_attention(
     block_table: jax.Array,  # (b, pages_per_seq) int32
     lengths: jax.Array,  # (b,) int32 — valid tokens AFTER this call's writes
     window: int | None = None,
+    page_axis: str | None = None,
 ):
     """Decode (or prefix-hit suffix prefill) against the page pool.
 
@@ -163,6 +206,8 @@ def paged_decode_attention(
     off-TPU both run the reference gather.  Calling this per suffix
     *chunk* (increasing ``index``/``lengths``) leaves page bytes
     identical to one call — the engine's chunked prefill relies on it.
+    ``page_axis``: the pool is sharded over that mesh axis and this runs
+    inside a ``shard_map`` over it (see :func:`write_and_attend`).
     """
     if window is not None:
         raise NotImplementedError(
@@ -178,17 +223,13 @@ def paged_decode_attention(
     page_ids = jnp.where(
         valid, jnp.take_along_axis(block_table, page_slot, axis=1), 0
     )
-    k_pages = paged_write(
-        cache.k_pages, k_new.astype(cache.k_pages.dtype), page_ids, rows
+    dtype = cache.k_pages.dtype
+    o, pages = write_and_attend(
+        q, tuple(cache), (k_new.astype(dtype), v_new.astype(dtype)),
+        block_table, page_ids, rows, positions[:, 0], lengths,
+        softcap=cfg.logit_softcap, page_axis=page_axis,
     )
-    v_pages = paged_write(
-        cache.v_pages, v_new.astype(cache.v_pages.dtype), page_ids, rows
-    )
-    o = kernels.op("paged_attention")(
-        q, k_pages, v_pages, block_table, positions[:, 0], lengths,
-        softcap=cfg.logit_softcap,
-    )
-    return _proj_out(params, o, cfg), PagedKvCache(k_pages=k_pages, v_pages=v_pages)
+    return _proj_out(params, o, cfg), PagedKvCache(*pages)
 
 
 def _qkv(params, x, cfg: AttnConfig, positions):

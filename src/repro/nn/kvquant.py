@@ -16,7 +16,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro import kernels
 from repro.configs.base import AttnConfig
 from repro.nn.attention import (
     KvCache,
@@ -24,7 +23,7 @@ from repro.nn.attention import (
     _proj_out,
     _qkv,
     paged_positions,
-    paged_write,
+    write_and_attend,
 )
 
 
@@ -152,6 +151,7 @@ def quant_paged_decode_attention(
     block_table: jax.Array,
     lengths: jax.Array,
     window: int | None = None,
+    page_axis: str | None = None,
 ):
     """`attention.paged_decode_attention` against int8 pages: new K/V
     rows are quantised on the way in, the attention gather dequantises
@@ -173,16 +173,12 @@ def quant_paged_decode_attention(
     )
     kq_new, ks_new = quantize_kv(k_new)
     vq_new, vs_new = quantize_kv(v_new)
-    kq = paged_write(cache.k_pages, kq_new, page_ids, rows)
-    vq = paged_write(cache.v_pages, vq_new, page_ids, rows)
-    ks = paged_write(cache.k_scale, ks_new, page_ids, rows)
-    vs = paged_write(cache.v_scale, vs_new, page_ids, rows)
-    o = kernels.op("paged_attention")(
-        q, kq, vq, block_table, positions[:, 0], lengths, ks, vs,
-        softcap=cfg.logit_softcap,
+    o, pages = write_and_attend(
+        q, tuple(cache), (kq_new, vq_new, ks_new, vs_new),
+        block_table, page_ids, rows, positions[:, 0], lengths,
+        softcap=cfg.logit_softcap, page_axis=page_axis,
     )
-    new_cache = QuantPagedKvCache(k_pages=kq, v_pages=vq, k_scale=ks, v_scale=vs)
-    return _proj_out(params, o, cfg), new_cache
+    return _proj_out(params, o, cfg), QuantPagedKvCache(*pages)
 
 
 def cache_bytes(cache) -> int:

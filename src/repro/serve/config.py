@@ -62,9 +62,10 @@ class ServeConfig:
                         type_=int)
     cache_len: int = _f(256, "per-request KV capacity in tokens", type_=int)
     page_size: int = _f(16, "tokens per KV page", type_=int)
-    pages: int | None = _f(None, "total pool pages incl. the null page "
-                           "(default: 1 + max_slots * cache_len/page_size, "
-                           "rounded up to fill whole shards)", type_=int)
+    pages: int | None = _f(None, "total pool pages incl. one null page per "
+                           "shard (default: max_slots * cache_len/page_size "
+                           "rounded up to fill whole shards, plus the nulls)",
+                           type_=int)
     kv_dtype: str = _f("bf16", "KV page storage dtype", type_=str,
                        choices=_KV_DTYPES)
     prompt_bucket: int = _f(16, "prefill length bucket (compile granularity)",
@@ -137,20 +138,19 @@ class ServeConfig:
             if self.pages_per_shard < 1:
                 raise ValueError(
                     f"need pages_per_shard >= 1: {self.pages_per_shard}")
-            implied = 1 + self.num_shards * self.pages_per_shard
+            implied = self.num_shards * (self.pages_per_shard + 1)
             if self.pages is not None and self.pages != implied:
                 raise ValueError(
                     f"pages={self.pages} contradicts pages_per_shard="
                     f"{self.pages_per_shard} x num_shards={self.num_shards} "
                     f"(implies {implied})")
         elif self.pages is not None:
-            if self.pages < 2:
-                raise ValueError(f"need pages >= 2: {self.pages}")
-            if (self.pages - 1) % self.num_shards:
+            if self.pages % self.num_shards \
+                    or self.pages // self.num_shards < 2:
                 raise ValueError(
-                    f"pages-1 ({self.pages - 1}) must divide evenly over "
-                    f"num_shards={self.num_shards} (page 0 is the shared "
-                    f"null page; every shard owns an equal range)")
+                    f"pages ({self.pages}) must split into num_shards="
+                    f"{self.num_shards} equal blocks of at least 2 (each "
+                    f"block is one shard's null page plus its own pages)")
         if self.sampler not in SAMPLERS:
             raise ValueError(
                 f"unknown sampler {self.sampler!r} (have {SAMPLERS})")
@@ -191,10 +191,10 @@ class ServeConfig:
 
     @property
     def num_pages(self) -> int | None:
-        """Total pool pages (incl. null page), or None for the engine's
-        workload-sized default."""
+        """Total pool pages (incl. every shard's null page), or None for
+        the engine's workload-sized default."""
         if self.pages_per_shard is not None:
-            return 1 + self.num_shards * self.pages_per_shard
+            return self.num_shards * (self.pages_per_shard + 1)
         return self.pages
 
     def fault_plan(self) -> FaultPlan | None:

@@ -75,9 +75,15 @@ shard hits locally.  ``broadcast_*`` counters account the payload and
 the per-device fabric bytes under the configured ``mcast_mode``
 (``dist.mcast.bytes_model(per_device=True)`` — the unicast / sw_tree /
 hw hierarchy the HLO-level collectives in ``dist/mcast.py`` realise).
-Passing ``mesh=`` shards the device page arrays over
-``config.mesh_axis`` along the page axis (GSPMD inserts the actual
-cross-device collectives); without a mesh the same sharded bookkeeping
+Passing ``mesh=`` (one device per shard along ``config.mesh_axis``)
+shards the device page arrays along the page axis so that each shard's
+page block is exactly one device's slice.  Each model step then runs as
+one ``shard_map`` program per device (:func:`per_device`): the model
+replicated, the paged attention on the device that owns each row's
+pages (``nn.attention.write_and_attend``), one ``psum`` of the attention
+output.  GSPMD moves the bytes of page copies and broadcasts.  Every
+slot's pages live on its own shard (a cross-shard fork broadcasts the
+parent's chain first).  Without a mesh the same sharded bookkeeping
 runs on one device, which is what tier-1 tests.  ``num_shards=1`` is
 the bitwise-identical PR 4-7 engine.
 """
@@ -171,6 +177,20 @@ def _page_tree_map(fn, caches, *rest):
     return jax.tree.map(fn, caches, *rest, is_leaf=_is_paged_leaf)
 
 
+def per_device(step, mesh, axis: str, n_args: int):
+    """``step(params, caches, *rest) -> (logits, caches)`` as one program
+    per device of a page pool sharded over ``mesh``'s ``axis``: params
+    and inputs replicated, each device's caches its own page block
+    (dim 2 of every leaf).  The model runs replicated (Mosaic kernels
+    cannot be partitioned by GSPMD) and its paged attention, built with
+    ``page_axis=axis``, works on the local block."""
+    pool = PartitionSpec(None, None, axis)
+    rep = PartitionSpec()
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(rep, pool) + (rep,) * (n_args - 2),
+                         out_specs=(rep, pool), check_vma=False)
+
+
 class PagedEngine:
     """Continuous-batching server over the paged KV subsystem.
 
@@ -209,28 +229,30 @@ class PagedEngine:
         num_pages = config.num_pages
         if num_pages is None:
             # the dense fallback's footprint: one full-length cache per
-            # batch slot, plus the null page — rounded up so every shard
-            # owns an equal page range AND can hold at least one
-            # full-length request (admission routes a request to a
+            # batch slot, plus one null page per shard — rounded up so
+            # every shard owns an equal page block AND can hold at least
+            # one full-length request (admission routes a request to a
             # single shard)
             per_shard = max(
                 -(-self.max_batch * self.table_width // self.num_shards),
                 self.table_width)
-            num_pages = 1 + self.num_shards * per_shard
+            num_pages = self.num_shards * (per_shard + 1)
         self.pool = PagePool(num_pages, page_size, num_shards=self.num_shards)
         self.prefix = PrefixCache(self.pool, page_size)
         self.sched = Scheduler(self.pool, self.prefix,
                                watermark=config.watermark)
-        # with a mesh, the device page arrays are sharded over the page
-        # axis; GSPMD needs the page count divisible by the axis size
-        # (the logical pool keeps num_pages — the trailing pad pages are
-        # never allocated)
-        self.num_device_pages = num_pages
+        # with a mesh, the device page arrays are sharded evenly over the
+        # page axis, one device per shard: each shard's page block is
+        # then exactly one device's slice
         if mesh is not None:
             n_dev = dict(mesh.shape)[self.mesh_axis]
-            self.num_device_pages = -(-num_pages // n_dev) * n_dev
+            if n_dev != self.num_shards:
+                raise ValueError(
+                    f"mesh axis {self.mesh_axis!r} has {n_dev} devices but "
+                    f"num_shards={self.num_shards}: the sharded pool needs "
+                    f"one device per shard")
         self.caches = lm.init_paged_cache(
-            cfg, self.num_device_pages, page_size, config.kv_dtype)
+            cfg, num_pages, page_size, config.kv_dtype)
         if mesh is not None:
             def shard_leaf(a):
                 spec = PartitionSpec(
@@ -254,7 +276,7 @@ class PagedEngine:
         self.broadcast_payload_bytes = 0
         self.broadcast_fabric_bytes = 0.0
         total_bytes = sum(a.nbytes for a in jax.tree.leaves(self.caches))
-        self.page_nbytes = total_bytes // self.num_device_pages
+        self.page_nbytes = total_bytes // num_pages
         self._fabric_mult = mcast.bytes_model(
             1, self.num_shards, per_device=True)[self.mcast_mode]
         self._fabric_mult_unicast = mcast.bytes_model(
@@ -305,20 +327,31 @@ class PagedEngine:
         # retry (part of the measured guard overhead).
         donate = () if self.kernel_fallback else (1,)
 
+        # with a mesh, each model step is one program per device over
+        # its own page block (see per_device)
+        page_axis = self.mesh_axis if mesh is not None else None
+
         def decode(p, c, t, i, bt, ln):
-            return lm.decode_step(p, cfg, c, t, i, block_table=bt, lengths=ln)
+            return lm.decode_step(p, cfg, c, t, i, block_table=bt, lengths=ln,
+                                  page_axis=page_axis)
 
         def cold_prefill(p, caches, toks, li, table_row, length):
             logits, dense = lm.prefill(p, cfg, toks, logit_index=li)
-            return logits, lm.prefill_to_pages(dense, caches, table_row, length)
+            return logits, lm.prefill_to_pages(dense, caches, table_row, length,
+                                               page_axis=page_axis)
 
         def suffix_prefill(p, caches, toks, li, table, index, length):
             logits, new_caches = lm.decode_step(
-                p, cfg, caches, toks, index, block_table=table, lengths=length
+                p, cfg, caches, toks, index, block_table=table, lengths=length,
+                page_axis=page_axis,
             )
             sel = jax.lax.dynamic_slice_in_dim(logits, li, 1, axis=1)
             return sel, new_caches
 
+        if mesh is not None:
+            decode = per_device(decode, mesh, page_axis, 6)
+            cold_prefill = per_device(cold_prefill, mesh, page_axis, 6)
+            suffix_prefill = per_device(suffix_prefill, mesh, page_axis, 7)
         self._builders = {
             "decode": decode,
             "cold_prefill": cold_prefill,
@@ -780,22 +813,29 @@ class PagedEngine:
              shard: int | None = None) -> int | None:
         """Fork a running request: the child shares *every* page of the
         parent (one refcount bump per page — no copies); the next write
-        to the shared tail page copy-on-writes.  Returns the child slot.
+        to the shared tail page copy-on-writes.  Returns the child slot,
+        or None when no slot (or, cross-shard, no page) is free.
 
-        ``shard`` routes the child's *future* allocations (page faults,
-        COW copies) to another shard — a cross-shard fork keeps reading
-        the parent's pages where they are and localises only its
-        divergence; default is the parent's shard (or the request's
-        pinned one)."""
+        ``shard`` places the child on another shard (default: the
+        parent's, or the request's pinned one).  A slot's pages all live
+        on its own shard, so a cross-shard fork broadcasts the parent's
+        chain into fresh pages there instead of sharing it."""
         child_slot = self._free_slot()
         if child_slot is None:
             return None
         st = self.slots[slot]
         if shard is None:
             shard = st.shard if req.shard is None else req.shard
-        self.pool.share(st.pages)
+        if shard == st.shard:
+            self.pool.share(st.pages)
+            pages = list(st.pages)
+        else:
+            pages = self.pool.alloc(len(st.pages), shard)
+            if pages is None:
+                return None
+            self._broadcast_chain(st.pages, pages)
         self.slots[child_slot] = _Slot(
-            req=req, pages=list(st.pages), length=st.length,
+            req=req, pages=pages, length=st.length,
             last_tok=st.last_tok, admit_seq=self._admit_seq, shard=shard,
         )
         self._admit_seq += 1
@@ -842,9 +882,7 @@ class PagedEngine:
                     return False
                 st.pages.extend(got)
             elif self.pool.refcount(st.pages[need]) > 1:
-                # the private copy lands on the slot's own shard — a
-                # forked child routed cross-shard localises its
-                # divergence here
+                # the private copy lands on the slot's own shard
                 res = self.pool.cow(st.pages[need], st.shard)
                 if res is None:  # pool dry: make room, then retry the COW
                     got = self._alloc_for_decode(

@@ -8,7 +8,7 @@ This module is the detection layer:
 
 * :func:`check_pool` (surfaced as ``PagePool.check()``) — structural
   audit of the pool: free-list disjointness, refcount/free-list
-  consistency, null-page-0 sanity, and — given the current *holders*
+  consistency, null-page sanity (every shard's), and — given the current *holders*
   (every live page-id chain: running slots, prefix-tree nodes,
   in-flight match refs) — an exact cross-count of every page's refcount
   against who actually holds it.  A rejected admission, a preemption, a
@@ -37,8 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-NULL_PAGE = 0  # mirrors pagepool.NULL_PAGE (no import: pagepool imports us)
-
 
 class GuardViolation(AssertionError):
     """An audited invariant does not hold.  Subclasses AssertionError so
@@ -57,8 +55,8 @@ def check_pool(pool, holders: Iterable[Sequence[int]] | None = None) -> None:
       refcount 0 iff it sits on the free list (a page in neither place
       is leaked capacity; a page in both is a double grant waiting to
       happen); no negative refcounts;
-    * **null-page sanity** — page 0 is never on the free list, never
-      refcounted, and the pool's in_use/free accounting adds up.
+    * **null-page sanity** — no shard's null page is ever on the free
+      list or refcounted, and the pool's in_use/free accounting adds up.
 
     With ``holders`` (an iterable of page-id chains — each occurrence of
     a page id in any chain is one expected reference): every page's
@@ -77,13 +75,17 @@ def check_pool(pool, holders: Iterable[Sequence[int]] | None = None) -> None:
                 f"shard {s} free list holds pages owned by another shard: "
                 f"{stray} — per-shard containment violated"
             )
-    if NULL_PAGE in free_set:
-        raise GuardViolation("null page 0 is on the free list")
-    if pool._ref[NULL_PAGE] != 0:
-        raise GuardViolation(
-            f"null page 0 has refcount {pool._ref[NULL_PAGE]} (must stay 0)"
-        )
-    for pid in range(1, pool.num_pages):
+    nulls = range(0, pool.num_pages, pool.block)
+    for null in nulls:
+        if null in free_set:
+            raise GuardViolation(f"null page {null} is on the free list")
+        if pool._ref[null] != 0:
+            raise GuardViolation(
+                f"null page {null} has refcount {pool._ref[null]} (must stay 0)"
+            )
+    for pid in range(pool.num_pages):
+        if pool.is_null(pid):
+            continue
         ref = pool._ref[pid]
         if ref < 0:
             raise GuardViolation(f"page {pid}: negative refcount {ref}")
@@ -93,19 +95,23 @@ def check_pool(pool, holders: Iterable[Sequence[int]] | None = None) -> None:
                 f"page {pid}: refcount {ref} but {state} — refcount 0 and "
                 f"free-list membership must coincide"
             )
-    if pool.in_use + pool.free_pages != pool.num_pages - 1:
+    if pool.in_use + pool.free_pages != pool.usable_pages:
         raise GuardViolation(
             f"pool accounting: in_use {pool.in_use} + free {pool.free_pages} "
-            f"!= {pool.num_pages - 1} usable pages"
+            f"!= {pool.usable_pages} usable pages"
         )
     if holders is None:
         return
     expected: Counter[int] = Counter()
     for chain in holders:
         expected.update(chain)
-    if expected.get(NULL_PAGE):
-        raise GuardViolation("a holder chain references the null page 0")
-    for pid in range(1, pool.num_pages):
+    for null in nulls:
+        if expected.get(null):
+            raise GuardViolation(
+                f"a holder chain references the null page {null}")
+    for pid in range(pool.num_pages):
+        if pool.is_null(pid):
+            continue
         if pool._ref[pid] != expected.get(pid, 0):
             raise GuardViolation(
                 f"page {pid}: refcount {pool._ref[pid]} != {expected.get(pid, 0)} "

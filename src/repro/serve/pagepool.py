@@ -26,18 +26,21 @@ Page ``0`` is reserved as the **null page**: the device write path
 redirects out-of-range / padded-position writes there, so it is never
 granted to a request and its contents are garbage by design.
 
-**Mesh sharding** (``num_shards > 1``): the non-null pages are
-partitioned into ``num_shards`` equal contiguous ranges — shard ``s``
-owns global ids ``[1 + s*pps, 1 + (s+1)*pps)`` — each with its own free
-list, so a block table's global page id *is* the ``(shard, local_page)``
-pair: ``shard_of(pid) = (pid-1) // pps``, ``local_page(pid) =
-(pid-1) % pps``.  Device page arrays are sharded over the mesh along
-the page axis with exactly this split, so a page allocated from shard
-``s``'s free list physically lives on device ``s``.  Refcounts stay one
-flat host-side array (the fanout mask is global — a broadcast copy on
-another shard is a *different page id* with its own refcount).  With
-``num_shards=1`` every code path degenerates to the PR 4-7 pool
-bit-for-bit: one free list, same grant order, same stats.
+**Mesh sharding** (``num_shards > 1``): the pool is split into
+``num_shards`` equal contiguous *blocks* of ``pages_per_shard + 1``
+pages.  Shard ``s`` owns block ``[s*B, (s+1)*B)`` with ``B =
+pages_per_shard + 1``; the block's first page ``s*B`` is that shard's
+own null page and the rest are its allocatable pages, each shard with
+its own free list.  A block is exactly one device's slice of the page
+axis when the device page arrays are sharded evenly over the mesh, so a
+page allocated from shard ``s``'s free list physically lives on device
+``s`` and ``local_page(pid) = pid - s*B`` is its index there (device
+code redirects pages another device owns to its local null page 0).
+Refcounts stay one flat host-side array (the fanout mask is global — a
+broadcast copy on another shard is a *different page id* with its own
+refcount).  With ``num_shards=1`` the block is the whole pool and every
+code path degenerates to the PR 4-7 pool bit-for-bit: null page 0, one
+free list over ``[1, num_pages)``, same grant order, same stats.
 """
 from __future__ import annotations
 
@@ -48,7 +51,7 @@ from typing import Iterable, Sequence
 from repro.obs import trace
 from repro.serve import faults
 
-NULL_PAGE = 0
+NULL_PAGE = 0  # shard 0's null page; the only one when num_shards=1
 
 
 @dataclasses.dataclass
@@ -67,27 +70,29 @@ class PoolStats:
 class PagePool:
     """Fixed pool of ``num_pages`` page ids, each covering ``page_size``
     token positions in every layer's device page array, partitioned into
-    ``num_shards`` equal per-shard free lists (default 1)."""
+    ``num_shards`` equal blocks (default 1), each led by its own null
+    page and holding one shard's free list."""
 
     def __init__(self, num_pages: int, page_size: int, *, num_shards: int = 1):
-        if num_pages < 2:
-            raise ValueError("need at least 2 pages (page 0 is the null page)")
         if page_size < 1:
             raise ValueError("page_size must be positive")
         if num_shards < 1:
             raise ValueError("num_shards must be positive")
-        if (num_pages - 1) % num_shards:
+        if num_pages % num_shards:
             raise ValueError(
-                f"num_pages-1 ({num_pages - 1}) must divide evenly over "
-                f"num_shards={num_shards} (equal per-shard page ranges)")
+                f"num_pages ({num_pages}) must divide evenly over "
+                f"num_shards={num_shards} (one equal page block per shard)")
+        if num_pages // num_shards < 2:
+            raise ValueError("need at least 2 pages per shard (each shard's "
+                             "first page is its null page)")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.num_shards = int(num_shards)
-        self.pages_per_shard = (self.num_pages - 1) // self.num_shards
+        self.block = self.num_pages // self.num_shards
+        self.pages_per_shard = self.block - 1
         self._ref = [0] * self.num_pages
         self._free: list[deque[int]] = [
-            deque(range(1 + s * self.pages_per_shard,
-                        1 + (s + 1) * self.pages_per_shard))
+            deque(range(s * self.block + 1, (s + 1) * self.block))
             for s in range(self.num_shards)
         ]
         self.stats = PoolStats()
@@ -98,9 +103,14 @@ class PagePool:
         return sum(len(f) for f in self._free)
 
     @property
+    def usable_pages(self) -> int:
+        """Pages that can ever be granted (every page but the nulls)."""
+        return self.num_shards * self.pages_per_shard
+
+    @property
     def in_use(self) -> int:
-        """Pages currently referenced (excludes the null page)."""
-        return self.num_pages - 1 - self.free_pages
+        """Pages currently referenced (excludes the null pages)."""
+        return self.usable_pages - self.free_pages
 
     def free_pages_on(self, shard: int) -> int:
         return len(self._free[shard])
@@ -109,18 +119,24 @@ class PagePool:
         """All free page ids, across every shard (audit surface)."""
         return [pid for f in self._free for pid in f]
 
+    def is_null(self, page_id: int) -> bool:
+        """True for a shard's null page (the first page of its block)."""
+        return page_id % self.block == 0
+
     def shard_of(self, page_id: int) -> int:
         """The shard owning ``page_id`` — the host half of the
         ``(shard, local_page)`` block-table mapping."""
-        if page_id == NULL_PAGE:
-            raise ValueError("the null page belongs to no shard")
-        return (page_id - 1) // self.pages_per_shard
+        if self.is_null(page_id):
+            raise ValueError(f"null page {page_id} belongs to no shard")
+        return page_id // self.block
 
     def local_page(self, page_id: int) -> int:
-        """``page_id``'s index within its owning shard's range."""
-        if page_id == NULL_PAGE:
-            raise ValueError("the null page belongs to no shard")
-        return (page_id - 1) % self.pages_per_shard
+        """``page_id``'s index within its shard's block — its index on
+        the owning device when the page axis is sharded (1-based: local
+        page 0 is the device's null page)."""
+        if self.is_null(page_id):
+            raise ValueError(f"null page {page_id} belongs to no shard")
+        return page_id % self.block
 
     def refcount(self, page_id: int) -> int:
         return self._ref[page_id]
@@ -163,8 +179,8 @@ class PagePool:
         refcount 0 and went back on their owning shard's free list."""
         freed = []
         for pid in page_ids:
-            if pid == NULL_PAGE:
-                raise ValueError("release of the null page")
+            if self.is_null(pid):
+                raise ValueError(f"release of the null page {pid}")
             if self._ref[pid] <= 0:
                 raise ValueError(f"release of unreferenced page {pid}")
             self._ref[pid] -= 1
@@ -187,9 +203,8 @@ class PagePool:
         ``new_id <- page_id`` and use ``new_id`` from now on; the old
         reference is released), or ``None`` when the pool is dry.
 
-        ``shard`` places the private copy (a cross-shard COW is how a
-        forked request diverging on another shard localises its writes);
-        the default keeps the copy on ``page_id``'s own shard."""
+        ``shard`` places the private copy; the default keeps it on
+        ``page_id``'s own shard."""
         if self._ref[page_id] <= 0:
             raise ValueError(f"cow of unreferenced page {page_id}")
         if self._ref[page_id] == 1:

@@ -169,13 +169,13 @@ class ServeLoop:
         self._warm_suffix: set[int] = set()
         self._warm_decode = False
         self._warm_verify = False
+        self._error: BaseException | None = None  # first worker failure
         self._threads = [
-            threading.Thread(target=self._prefill_worker,
-                             name="serve-prefill", daemon=True),
-            threading.Thread(target=self._decode_worker,
-                             name="serve-decode", daemon=True),
-            threading.Thread(target=self._emit_worker,
-                             name="serve-emit", daemon=True),
+            threading.Thread(target=self._guarded, args=(worker,),
+                             name=name, daemon=True)
+            for worker, name in ((self._prefill_worker, "serve-prefill"),
+                                 (self._decode_worker, "serve-decode"),
+                                 (self._emit_worker, "serve-emit"))
         ]
         for t in self._threads:
             t.start()
@@ -202,6 +202,9 @@ class ServeLoop:
         queue — is REJECTED here with a typed reason."""
         t = arrival_t if arrival_t is not None else self.clock()
         with self._work:
+            if self._error is not None:
+                raise RuntimeError("ServeLoop stopped: a worker failed") \
+                    from self._error
             if self._closing:
                 raise RuntimeError("ServeLoop is closed to new submissions")
             if rid is None:
@@ -272,6 +275,27 @@ class ServeLoop:
             and not self.engine.slots and not self.engine._requeue
 
     # -- workers ------------------------------------------------------------
+    def _guarded(self, worker) -> None:
+        """Run one worker; on any exception record it (``close`` and
+        ``run_trace`` re-raise it) and abort the loop, so a compile or
+        device error fails the requests instead of hanging the drain."""
+        try:
+            worker()
+        except Exception as e:  # noqa: BLE001 — re-raised from close()
+            with self._work:
+                if self._error is None:
+                    self._error = e
+                self._abort = True
+                for sreq in self._queue:
+                    self._finish_locked(sreq, Lifecycle.FAILED, repr(e))
+                self._queue.clear()
+                for slot, st in list(self.engine.slots.items()):
+                    self.engine.pool.release(st.pages)
+                    del self.engine.slots[slot]
+                    self._finish_locked(self._by_rid[st.req.rid],
+                                        Lifecycle.FAILED, repr(e))
+                self._work.notify_all()
+
     def _prefill_worker(self) -> None:
         eng = self.engine
         while True:
@@ -533,6 +557,8 @@ class ServeLoop:
             self.warmup_for_trace(trace)
         t0 = self.clock()
         for a in trace:
+            if self._error is not None:
+                break  # close() re-raises the worker's exception
             if realtime:
                 delay = a.t * time_scale - (self.clock() - t0)
                 if delay > 0:
@@ -545,7 +571,8 @@ class ServeLoop:
         """Stop accepting submissions; with ``drain`` let every queued
         and live request finish, otherwise abort live work as FAILED
         ("shutdown").  Flushes the emit queue and joins the workers —
-        after close every stream has ended."""
+        after close every stream has ended.  Re-raises the first
+        exception a worker died with."""
         with self._work:
             self._closing = True
             if not drain:
@@ -560,6 +587,11 @@ class ServeLoop:
                 raise TimeoutError(f"{t.name} did not stop within {timeout}s")
         self._emit_q.put(("stop",))
         self._threads[2].join(timeout)
+        if self._error is not None:
+            for sreq in self._by_rid.values():
+                if not sreq.stream.closed.is_set():  # emit worker died
+                    sreq.stream._close()
+            raise self._error
 
     # -- introspection ------------------------------------------------------
     def snapshot(self) -> dict:

@@ -8,7 +8,9 @@ One scenario family: a 4-shard paged engine whose device page arrays are
 workload, and every mcast mode must produce token streams identical to
 the single-host single-shard oracle running in the same process — with
 the prefix chain allocated once on its owning shard and broadcast (not
-re-prefilled) to the rest, per the engine's counters.
+re-prefilled) to the rest, per the engine's counters.  Each model step
+runs as one shard_map program per device over its own page block; an
+int8 pool leg checks the quantised pages and scales take the same path.
 """
 import os
 
@@ -69,6 +71,19 @@ def main() -> None:
         assert st["broadcast_payload_bytes"] == 12 * eng.page_nbytes, st
         eng.check()
         print(f"OK mesh_serve_{mode}")
+
+    # int8 page pools ride the same per-device program: the quantised
+    # pages and their scales are sharded alike
+    int8_one = PagedEngine(cfg, params, config=ServeConfig(
+        max_slots=2, cache_len=64, page_size=8, pages=33, kv_dtype="int8"))
+    expect8 = {r.rid: r.out for r in int8_one.run(clone())}
+    eng = PagedEngine(cfg, params, mesh=mesh, config=ServeConfig(
+        max_slots=2, cache_len=64, page_size=8, num_shards=4,
+        pages_per_shard=8, kv_dtype="int8"))
+    got = {r.rid: r.out for r in eng.run(clone())}
+    assert got == expect8, (got, expect8)
+    eng.check()
+    print("OK mesh_serve_int8")
 
     print("ALL_DISTSERVE_OK")
 

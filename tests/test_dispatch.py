@@ -148,6 +148,29 @@ def test_forced_pallas_backend_never_silently_substitutes_reference():
     assert (name, backend) == ("pallas", "pallas")
 
 
+def test_default_pallas_substitution_is_counted_and_warned(monkeypatch):
+    """On the chip (default backend pallas) a problem no pallas schedule
+    can take is resolved to the reference backend — visibly: counted in
+    ``fallback_stats().substitutions`` on every resolve and warned once
+    per (op, problem)."""
+    import warnings
+
+    monkeypatch.setattr(api, "_interpret", lambda: False)
+    kernels.reset_fallback_stats()
+    shape = (1, 1, 256, 2048, 2048)  # SSD state too big for VMEM
+    with pytest.warns(RuntimeWarning, match="no pallas schedule of 'ssd'"):
+        assert kernels.resolve("ssd", shape, jnp.float32)[1] == "reference"
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # same (op, problem): counted, quiet
+        assert kernels.resolve("ssd", shape, jnp.float32)[1] == "reference"
+        assert kernels.resolve("ssd", (1, 1, 64, 16, 16), jnp.float32)[1] \
+            == "pallas"  # fits: no substitution
+    assert kernels.fallback_stats().substitutions == 2
+    assert kernels.fallback_stats().fallbacks == 0
+    kernels.reset_fallback_stats()
+    assert kernels.fallback_stats().substitutions == 0
+
+
 def test_unknown_op_and_schedule_raise():
     with pytest.raises(ValueError):
         kernels.op("conv2d")

@@ -237,6 +237,40 @@ def test_abort_shutdown_fails_live_work_cleanly(small):
 # warmup: cached per-bucket prefill executables
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("where", ["prefill", "decode"])
+def test_worker_exception_reraised_not_hung(small, where):
+    """A compile or device error inside a worker (here: the engine call
+    it makes raises) fails every request and comes back out of
+    ``run_trace``/``close`` — the drain neither hangs nor swallows it."""
+    cfg, params = small
+    eng = _mk_engine(cfg, params, num_pages=64)
+
+    class DeviceError(RuntimeError):
+        pass
+
+    def boom(*a, **k):
+        raise DeviceError(f"injected {where} failure")
+
+    if where == "prefill":
+        eng._admit = boom
+    else:
+        eng.step = boom
+    loop = ServeLoop(eng)
+    trace = _mk_trace(cfg, shared_prefix_len=0)
+    t0 = time.monotonic()
+    with pytest.raises(DeviceError, match=f"injected {where}"):
+        loop.run_trace(trace, warmup=False, realtime=False)
+    assert time.monotonic() - t0 < 30
+    results = loop._by_rid
+    assert results and all(r.state is Lifecycle.FAILED
+                           for r in results.values())
+    assert all(r.stream.closed.is_set() for r in results.values())
+    assert not eng.slots and eng.pool.free_pages == eng.pool.usable_pages
+    assert not any(t.is_alive() for t in loop._threads)
+    with pytest.raises(RuntimeError, match="worker failed"):
+        loop.submit([1, 2, 3], 2)
+
+
 def test_warmup_compiles_each_bucket_once(small):
     cfg, params = small
     eng = _mk_engine(cfg, params)

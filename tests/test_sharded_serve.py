@@ -3,8 +3,9 @@
 Covers the sharded serving stack on a single device (the forced-multi-
 device mesh legs live in test_dist_serve.py):
 
-* PagePool shard partition: (shard, local_page) mapping, per-shard free
-  lists, containment audit, single-shard degenerate grant order;
+* PagePool shard partition: one null-led page block per shard, the
+  (shard, local_page) mapping, per-shard free lists, containment audit,
+  single-shard degenerate grant order;
 * ServeConfig: validation, argparse derivation, the one-warning legacy
   keyword shim, bitwise S=1 parity between config and legacy call sites;
 * 4-shard engine == dense oracle (cold and shared-prefix), with the
@@ -74,43 +75,67 @@ def _clone(reqs):
 
 
 def test_pool_shard_partition_and_mapping():
-    p = PagePool(33, 8, num_shards=4)
-    assert p.pages_per_shard == 8
+    p = PagePool(36, 8, num_shards=4)
+    assert p.pages_per_shard == 8 and p.block == 9
     assert p.free_pages == 32
-    # shard s owns global ids [1 + s*8, 1 + (s+1)*8)
+    # shard s owns the block [9s, 9s + 9): its own null page 9s, then
+    # the allocatable ids [9s + 1, 9s + 9)
     for s in range(4):
         assert p.free_pages_on(s) == 8
         ids = p.alloc(2, s)
-        assert ids == [1 + s * 8, 2 + s * 8]
+        assert ids == [1 + s * 9, 2 + s * 9]
         assert [p.shard_of(i) for i in ids] == [s, s]
-        assert [p.local_page(i) for i in ids] == [0, 1]
+        assert [p.local_page(i) for i in ids] == [1, 2]
         p.release(ids)
     # a freed page returns to its OWNING shard's free list
     ids = p.alloc(3, 1)
     p.release(ids)
     assert p.free_pages_on(1) == 8
     p.check()
-    # the null page belongs to no shard
-    with pytest.raises(ValueError):
-        p.shard_of(0)
-    with pytest.raises(ValueError):
-        p.local_page(0)
+    # the null pages belong to no shard and are never granted or freed
+    for null in (0, 9, 18, 27):
+        assert p.is_null(null)
+        with pytest.raises(ValueError):
+            p.shard_of(null)
+        with pytest.raises(ValueError):
+            p.local_page(null)
+        with pytest.raises(ValueError):
+            p.release([null])
+
+
+@pytest.mark.parametrize("num_shards", [1, 4])
+def test_pool_shard_ids_fall_in_one_device_block(num_shards):
+    """Each shard's ids are exactly one device's slice of a page axis of
+    ``num_pages`` split evenly over ``num_shards`` devices; with one
+    shard the ids are the PR 4-7 pool's ``[1, num_pages)``."""
+    pps = 8
+    p = PagePool(num_shards * (pps + 1), 8, num_shards=num_shards)
+    blocks = np.array_split(np.arange(p.num_pages), num_shards)
+    for s, block in enumerate(blocks):
+        ids = p.alloc(pps, s)
+        assert set(ids) <= set(block[1:].tolist())  # block[0]: the null
+        assert p.alloc(1, s) is None  # the shard is full
+        assert [p.local_page(i) for i in ids] == list(range(1, pps + 1))
+    if num_shards == 1:
+        assert ids == list(range(1, p.num_pages))
 
 
 def test_pool_per_shard_exhaustion_is_contained():
-    p = PagePool(9, 8, num_shards=2)  # 4 pages per shard
+    p = PagePool(10, 8, num_shards=2)  # 4 pages per shard
     got = p.alloc(4, 0)
     assert got is not None and p.free_pages_on(0) == 0
     # shard 0 dry: an all-or-nothing grant there fails...
     assert p.alloc(1, 0) is None
     # ...while shard 1 still grants — per-shard failure containment
-    assert p.alloc(1, 1) == [5]
+    assert p.alloc(1, 1) == [6]
     p.check()
 
 
 def test_pool_shard_divisibility_enforced():
     with pytest.raises(ValueError):
-        PagePool(10, 8, num_shards=4)  # 9 usable pages don't split 4 ways
+        PagePool(10, 8, num_shards=4)  # 10 pages don't split 4 ways
+    with pytest.raises(ValueError):
+        PagePool(4, 8, num_shards=4)  # a block of 1 is just a null page
     with pytest.raises(ValueError):
         PagePool(9, 8, num_shards=0)
 
@@ -129,7 +154,7 @@ def test_pool_single_shard_degenerate_grant_order():
 
 
 def test_pool_cross_shard_cow_places_copy():
-    p = PagePool(9, 8, num_shards=2)
+    p = PagePool(10, 8, num_shards=2)
     (pid,) = p.alloc(1, 0)
     p.share([pid])  # two holders -> a write must copy
     new_pid, copied = p.cow(pid, shard=1)
@@ -167,13 +192,13 @@ def test_bytes_model_per_device_hierarchy():
 
 def test_serve_config_validation():
     c = ServeConfig(num_shards=4, pages_per_shard=8)
-    assert c.num_pages == 33
-    assert ServeConfig(pages=33, num_shards=4).num_pages == 33
+    assert c.num_pages == 36  # 4 blocks of a null page + 8
+    assert ServeConfig(pages=36, num_shards=4).num_pages == 36
     assert ServeConfig().num_pages is None  # engine sizes the default
     with pytest.raises(ValueError):
         ServeConfig(mcast_mode="carrier-pigeon")
     with pytest.raises(ValueError):
-        ServeConfig(pages=34, num_shards=4)  # 33 usable don't split 4 ways
+        ServeConfig(pages=34, num_shards=4)  # 34 don't split 4 ways
     with pytest.raises(ValueError):
         ServeConfig(pages=34, num_shards=4, pages_per_shard=8)  # contradiction
     with pytest.raises(ValueError):
@@ -307,7 +332,7 @@ def test_engine_default_pool_fills_whole_shards(small):
     eng = PagedEngine(cfg, params,
                       config=ServeConfig(max_slots=2, cache_len=64,
                                          page_size=8, num_shards=3))
-    assert (eng.pool.num_pages - 1) % 3 == 0
+    assert eng.pool.num_pages % 3 == 0
     assert eng.pool.pages_per_shard >= 64 // 8  # each shard fits a request
 
 
@@ -324,24 +349,36 @@ def test_fork_across_shards_cow_lands_on_child_shard(small):
     parent = Request(rid=0, prompt=list(range(10, 22)), max_new=6, shard=0)
     assert eng._admit(parent)
     (pslot,) = eng.slots
+    pages = list(eng.slots[pslot].pages)
+    # same-shard fork: a zero-copy share; its first write copies-on-write
+    # onto the same shard
+    twin = eng.fork(pslot, Request(rid=2, prompt=list(parent.prompt),
+                                   max_new=6))
+    tst = eng.slots[twin]
+    assert tst.pages == pages and tst.shard == 0
+    need = tst.length // eng.page_size
+    assert eng.pool.refcount(pages[need]) >= 2
+    assert eng._ensure_writable(twin)
+    assert tst.pages[need] != pages[need]
+    assert eng.pool.shard_of(tst.pages[need]) == 0
+    assert eng.n_cow == 1
+    # cross-shard fork: a slot's pages all live on its own shard, so the
+    # parent's chain is broadcast into fresh pages on the child's shard
     child_req = Request(rid=1, prompt=list(parent.prompt), max_new=6)
     cslot = eng.fork(pslot, child_req, shard=1)
     assert cslot is not None
     cst = eng.slots[cslot]
     assert cst.shard == 1
-    assert cst.pages == eng.slots[pslot].pages  # zero-copy share
-    # the child's next write hits a shared page -> COW onto ITS shard
-    need = cst.length // eng.page_size
-    shared_pid = cst.pages[need]
-    assert eng.pool.refcount(shared_pid) >= 2
-    assert eng._ensure_writable(cslot)
-    new_pid = cst.pages[need]
-    assert new_pid != shared_pid
-    assert eng.pool.shard_of(new_pid) == 1
-    assert eng.n_cow >= 1
+    assert len(cst.pages) == len(pages)
+    assert {eng.pool.shard_of(pid) for pid in cst.pages} == {1}
+    assert all(eng.pool.refcount(pid) == 1 for pid in cst.pages)
+    assert eng.n_broadcast_chains == 1
+    assert eng.n_broadcast_pages == len(pages)
+    assert eng._ensure_writable(cslot)  # exclusively owned: no copy
+    assert eng.n_cow == 1
     # both lineages decode to the same greedy continuation
     done = {r.rid: r.out for r in eng.run([])}
-    assert done[0] == done[1]
+    assert done[0] == done[1] == done[2]
     eng.check()
 
 
@@ -351,7 +388,7 @@ def test_fork_across_shards_cow_lands_on_child_shard(small):
 
 
 def test_scheduler_per_shard_watermark():
-    pool = PagePool(9, 8, num_shards=2)
+    pool = PagePool(10, 8, num_shards=2)
     sched = Scheduler(pool, PrefixCache(pool), watermark=1)
     # global headroom is plentiful, but shard 0 is the one that pays
     pool.alloc(3, 0)
@@ -401,7 +438,7 @@ def test_preemption_restricted_to_pressured_shard(small):
 
 
 def test_prefix_per_shard_copies_broadcast_and_evict():
-    pool = PagePool(9, 8, num_shards=2)
+    pool = PagePool(10, 8, num_shards=2)
     cache = PrefixCache(pool)
     toks = list(range(17))  # 2 full shareable pages + the decode page
     p0 = pool.alloc(2, 0)
