@@ -6,8 +6,12 @@ requests through ``ServeLoop`` over ``PagedEngine``, in-process through
 bf16 weights drawn from ``--seed``.  Cold prefill, paged decode and the
 ``pallas_prefill`` prefix-hit path all run.  Then the prefill and first
 decode logits of three requests are compared with the same requests under
-the reference kernel backend on the same chip, and each main-path kernel
-with its reference on identical full-width inputs.
+the reference kernel backend on the same chip.  The same requests also
+run in float32 (reference backend, matmul precision highest), which
+measures how far each bf16 backend drifts from exact arithmetic, and
+under two planted decode faults, which show what the comparison can see.
+Last, each main-path kernel is compared with its reference on identical
+full-width inputs shaped like the engine's own calls.
 
 ``--four-chip`` (four chips, one host) runs only the mesh-sharded page
 pool: the same seeded trace at ``--num-shards 4 --mesh`` in every
@@ -16,8 +20,10 @@ streams must be identical.
 
 The script fails (non-zero exit, no result line) when JAX finds no TPU,
 when a request does not drain, when any kernel site on the path resolved
-to the reference backend or fell back to it, or when logits or kernel
-outputs differ from the reference backend beyond the stated tolerances.  On success the last line of stdout is
+to the reference backend or fell back to it, when logits or kernel
+outputs differ from the reference backend beyond the stated tolerances,
+or when the planted row-swap fault reads within the logits tolerance.
+On success the last line of stdout is
 
     {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}
 
@@ -41,10 +47,13 @@ ARCH = "qwen1.5-0.5b"
 KERNEL_RTOL = 2**-4
 # End-to-end logits tolerance, same measure, per captured logits tensor.
 # With random weights a bf16 rounding difference compounds over the 24
-# layers: this model's bf16 logits differ from its own float32
-# evaluation by 0.25-0.28 (CPU, full width, 64 and 402 tokens), and two
-# bf16 evaluations that round at different points can be up to twice
-# that apart.  A kernel bug shows in the per-kernel check above.
+# layers, so two bf16 evaluations that round at different points drift
+# apart by up to the sum of their drifts from exact arithmetic.  The
+# run measures those drifts against a float32 evaluation and prints
+# them, and fails unless a planted fault (two decode rows swap block
+# tables) reads above this tolerance.  On a TPU v5e with seed 0: pallas
+# vs reference at most 0.427, each vs float32 at most 0.66, the planted
+# faults 1.39 (a page short) and 1.72 (rows swapped).
 LOGITS_RTOL = 0.6
 # paged-attention schedules the default phase must dispatch (``linear``
 # may use any pallas matmul schedule)
@@ -122,18 +131,91 @@ def peak_bytes(dev) -> int | None:
     return stats.get("peak_bytes_in_use")
 
 
+def cache_entries(path: str) -> int:
+    return len(os.listdir(path)) if os.path.isdir(path) else 0
+
+
+class CompileLog:
+    """What this process compiled, from ``jax.monitoring``: wall seconds
+    spent tracing, lowering and in backend compiles (which include reads
+    of the persistent cache), and the persistent cache's requests, hits
+    and writes.  A request that neither hits nor writes compiled faster
+    than ``jax_persistent_cache_min_compile_time_secs`` and is not
+    cached."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+    HIT = "/jax/compilation_cache/cache_hits"
+    WRITE = "/jax/compilation_cache/cache_misses"  # recorded on write
+    READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.counts: dict[str, int] = {}
+        self.spans: dict[str, list[tuple[float, float]]] = {}
+        self.read_s = 0.0
+        jax.monitoring.register_event_listener(self._event)
+        jax.monitoring.register_event_time_span_listener(self._span)
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, event, **_):
+        self.counts[event] = self.counts.get(event, 0) + 1
+
+    def _span(self, event, start, end, **_):
+        self.spans.setdefault(event, []).append((start, end))
+
+    def _duration(self, event, secs, **_):
+        if event == self.READ:
+            self.read_s += secs
+
+    def mark(self) -> tuple:
+        return (dict(self.counts), {k: len(v) for k, v in self.spans.items()},
+                self.read_s)
+
+    def summary(self, since: tuple = ({}, {}, 0.0)) -> str:
+        """One line for what happened after ``since`` (a ``mark()``);
+        nested traces overlap, so seconds are the union of the spans."""
+        import jax
+
+        def n(event):
+            return self.counts.get(event, 0) - since[0].get(event, 0)
+
+        def wall(event):
+            spans = sorted(self.spans.get(event, [])[since[1].get(event, 0):])
+            total, end = 0.0, float("-inf")
+            for a, b in spans:
+                total += max(0.0, b - max(a, end))
+                end = max(end, b)
+            return total, len(spans)
+
+        (tr, n_tr), (lo, n_lo), (co, n_co) = (
+            wall(self.TRACE), wall(self.LOWER), wall(self.COMPILE))
+        uncached = n(self.REQUEST) - n(self.HIT) - n(self.WRITE)
+        return (f"trace {tr:.3f} s ({n_tr} jits), lower {lo:.3f} s "
+                f"({n_lo}), backend compile or cache read {co:.3f} s "
+                f"({n_co}); persistent cache: {n(self.REQUEST)} requests, "
+                f"{n(self.HIT)} hits ({self.read_s - since[2]:.3f} s "
+                f"reading), {n(self.WRITE)} written, {uncached} compiled "
+                f"but not cached (under "
+                f"{jax.config.jax_persistent_cache_min_compile_time_secs} s)")
+
+
 # ---------------------------------------------------------------------------
 # default phase: one chip
 # ---------------------------------------------------------------------------
 
 
-def serve_phase(serve_argv):
+def serve_phase(serve_argv, compiles: CompileLog):
     """Serve the trace through the launcher with the recorder armed;
     returns the ServeRun and the recorder."""
     from repro.launch import serve
     from repro.obs import trace as obs_trace
 
     rec = obs_trace.start(obs_trace.Recorder(meta={"tool": "chip_smoke"}))
+    mark = compiles.mark()
     t0 = time.perf_counter()
     try:
         run = serve.main(serve_argv)
@@ -144,6 +226,7 @@ def serve_phase(serve_argv):
     compile_s = sum(e["dur"] for e in warm) / 1e6
     say(f"serve wall {wall:.3f} s, compile/warmup {compile_s:.3f} s "
         f"({sum(e['args']['programs'] for e in warm)} programs)")
+    say(f"serve compiles: {compiles.summary(mark)}")
     return run, rec
 
 
@@ -165,21 +248,69 @@ class _Tape:
         return pick if self.replay is None else self.replay[len(self.chosen) - 1]
 
 
-def capture_logits(cfg, params, config, reqs, policy, tape):
+def swap_decode_rows(name, args):
+    """Planted fault: in the decode step, rows 0 and 1 swap block tables
+    (each reads, and writes into, the other request's pages)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    if name != "decode":
+        return args
+    table = np.array(args[4])
+    table[[0, 1]] = table[[1, 0]]
+    return args[:4] + (jnp.asarray(table),) + args[5:]
+
+
+def drop_decode_page(page_size: int):
+    """Planted fault: the decode step's lengths are one page short, so
+    each row's attention misses its last ``page_size`` keys (a page-loop
+    bound one short; the new token's write is dropped too)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    def fault(name, args):
+        if name != "decode":
+            return args
+        lengths = np.maximum(np.asarray(args[5]) - page_size, 0)
+        return args[:5] + (jnp.asarray(lengths.astype(np.int32)),)
+    return fault
+
+
+def capture_logits(cfg, params, config, reqs, policy, tape, *, fault=None,
+                   f32=False):
     """Run ``reqs`` (max_new=2: prefill + one decode step) through a fresh
     sync engine under ``policy``, choosing tokens through ``tape``;
     return [(step name, host logits)], decode logits cut to the live
-    rows (slots 0.. in admission order)."""
+    rows (slots 0.. in admission order).  ``fault(name, args)`` rewrites
+    the arguments of each model step; ``f32`` runs params and pages in
+    float32 with matmul precision highest.  The pool holds just these
+    requests."""
+    import contextlib
+    import dataclasses
+    import gc
+
+    import jax
+    import jax.numpy as jnp
     import numpy as np
 
     from repro import kernels
     from repro.serve import PagedEngine, Request
 
+    config = dataclasses.replace(
+        config, pages=1 + len(reqs) * (config.cache_len // config.page_size))
+    if f32:
+        params = jax.tree.map(
+            lambda a: a.astype(jnp.float32)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
     eng = PagedEngine(cfg, params, config=config, sampler=tape)
+    if f32:
+        eng.caches = jax.tree.map(lambda a: a.astype(jnp.float32), eng.caches)
     got = []
     dispatch = eng._dispatch
 
     def spy(name, *args):
+        if fault is not None:
+            args = fault(name, args)
         out = dispatch(name, *args)
         logits = np.asarray(out[0], np.float32)
         if name == "decode":
@@ -188,10 +319,26 @@ def capture_logits(cfg, params, config, reqs, policy, tape):
         return out
 
     eng._dispatch = spy
-    with kernels.use_policy(policy):
+    with contextlib.ExitStack() as ctx:
+        ctx.enter_context(kernels.use_policy(policy))
+        if f32:
+            ctx.enter_context(jax.default_matmul_precision("highest"))
         eng.run([Request(rid=r.rid, prompt=list(r.prompt), max_new=2)
                  for r in reqs])
+    del eng, spy, dispatch, params
+    gc.collect()  # the spy closes a cycle over the engine and its pool
     return got
+
+
+def captures(page_size: int) -> tuple:
+    """The runs logits_phase compares with the bf16 reference run:
+    (column, kernel policy, capture_logits options)."""
+    return (
+        ("pallas", None, {}),
+        ("f32", "backend=reference", {"f32": True}),
+        ("swap", None, {"fault": swap_decode_rows}),
+        ("page", None, {"fault": drop_decode_page(page_size)}),
+    )
 
 
 def logits_phase(run, prefix_len: int) -> None:
@@ -208,30 +355,49 @@ def logits_phase(run, prefix_len: int) -> None:
     picks = groups[0][:2] + [g[0] for g in groups[1:2]]
     say(f"logits check on requests {[r.rid for r in picks]} "
         f"(prompt lengths {[len(r.prompt) for r in picks]})")
+    # the bf16 reference run picks the tokens; every other run replays
+    # them, so all runs feed their steps the same inputs
     ref_tape = _Tape()
     ref = capture_logits(eng.cfg, eng.params, eng.config, picks,
                          "backend=reference", ref_tape)
-    tape = _Tape(replay=ref_tape.chosen)
-    pallas = capture_logits(eng.cfg, eng.params, eng.config, picks, None,
-                            tape)
-    check([n for n, _ in pallas] == [n for n, _ in ref],
-          f"step sequences differ: {[n for n, _ in pallas]} vs "
-          f"{[n for n, _ in ref]}")
-    worst = 0.0
-    for (name, a), (_, b) in zip(pallas, ref):
-        check(bool(np.isfinite(a).all()), f"non-finite {name} logits")
-        rel = rel_diff(a, b)
-        worst = max(worst, rel)
-        say(f"logits {name} {a.shape}: max rel diff {rel:.6g}")
+    steps = [n for n, _ in ref]
+    runs, tapes = {"ref": ref}, {}
+    for col, policy, opts in captures(eng.page_size):
+        tapes[col] = _Tape(replay=ref_tape.chosen)
+        runs[col] = capture_logits(eng.cfg, eng.params, eng.config, picks,
+                                   policy, tapes[col], **opts)
+        check([n for n, _ in runs[col]] == steps,
+              f"{col} step sequence {[n for n, _ in runs[col]]} differs "
+              f"from the reference run's {steps}")
+    for col in ("pallas", "f32"):
+        for name, a in runs[col]:
+            check(bool(np.isfinite(a).all()), f"non-finite {col} {name} logits")
+    pairs = (("pallas", "ref"), ("ref", "f32"), ("pallas", "f32"),
+             ("swap", "ref"), ("page", "ref"))
+    table = {p: [rel_diff(runs[p[0]][i][1], runs[p[1]][i][1])
+                 for i in range(len(steps))] for p in pairs}
+    heads = [f"{a}-{b}" for a, b in pairs]
+    say("logits max rel diff per step, max |a-b| / max |b|:")
+    say(f"{'step':<16}" + "".join(f"{h:>12}" for h in heads))
+    for i, name in enumerate(steps):
+        say(f"{name:<16}" + "".join(f"{table[p][i]:>12.6g}" for p in pairs))
+    worst = {p: max(v) for p, v in table.items()}
+    say(f"{'max':<16}" + "".join(f"{worst[p]:>12.6g}" for p in pairs))
     agree = sum(int((x[:len(y)] == y).sum())
-                for x, y in zip(tape.chosen, ref_tape.chosen))
+                for x, y in zip(tapes["pallas"].chosen, ref_tape.chosen))
     total = sum(y.size for y in ref_tape.chosen)
     say(f"logits tolerance {LOGITS_RTOL} (max |pallas-ref| / max |ref|), "
-        f"measured max rel diff {worst:.6g}")
+        f"measured max rel diff {worst['pallas', 'ref']:.6g}; planted "
+        f"row-swap fault {worst['swap', 'ref']:.6g} (must exceed it), "
+        f"dropped-page fault {worst['page', 'ref']:.6g} (reported)")
     say(f"greedy-token agreement {agree}/{total} (reported, not gated)")
-    check(worst <= LOGITS_RTOL,
-          f"logits differ from the reference backend: {worst:.6g} > "
-          f"{LOGITS_RTOL}")
+    check(worst["pallas", "ref"] <= LOGITS_RTOL,
+          f"logits differ from the reference backend: "
+          f"{worst['pallas', 'ref']:.6g} > {LOGITS_RTOL}")
+    check(worst["swap", "ref"] > LOGITS_RTOL,
+          f"the planted row-swap fault reads {worst['swap', 'ref']:.6g}, "
+          f"within the tolerance {LOGITS_RTOL}: the logits check cannot "
+          f"see it")
 
 
 def rel_diff(a, b) -> float:
@@ -243,9 +409,12 @@ def rel_diff(a, b) -> float:
 
 def kernel_phase(cfg, seed: int) -> None:
     """Each main-path kernel against the reference backend on identical
-    inputs at full width: paged decode (8 rows, 2048-token table),
-    pallas_prefill (a 64-token chunk, bf16 and int8 pages) and the tiled
-    linear with its fused bias + silu epilogue."""
+    inputs at full width, shaped like the engine's calls: paged decode
+    (8 rows, 2048-token table, two idle rows of length 0),
+    pallas_prefill (a full 64-token chunk, bf16 and int8 pages, and a
+    bucket-padded last chunk of 18 tokens at position 384) and the tiled
+    linear with its fused bias + silu epilogue.  Rows the engine
+    discards (idle slots, bucket padding) are not compared."""
     import jax
     import jax.numpy as jnp
 
@@ -253,7 +422,7 @@ def kernel_phase(cfg, seed: int) -> None:
     from repro.nn.kvquant import quantize_kv
 
     kvh, h, d = cfg.attn.n_kv_heads, cfg.attn.n_heads, cfg.attn.head_dim
-    b, width, ps = 8, 128, 16
+    b, width, ps, idle = 8, 128, 16, 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     n_pages = b * width + 1
     kp = jax.random.normal(ks[0], (kvh, n_pages, ps, d), jnp.bfloat16)
@@ -261,6 +430,10 @@ def kernel_phase(cfg, seed: int) -> None:
     table = (jax.random.permutation(ks[2], b * width) + 1).reshape(b, width)
     table = table.astype(jnp.int32)
     lengths = jax.random.randint(ks[3], (b,), 65, width * ps + 1, jnp.int32)
+    # idle decode slots: length 0, index 0, an all-null table row
+    dec_lengths = lengths.at[b - idle:].set(0)
+    dec_table = table.at[b - idle:].set(0)
+    dec_index = jnp.maximum(dec_lengths - 1, 0)
     q1 = jax.random.normal(ks[4], (b, 1, h, d), jnp.bfloat16)
     q64 = jax.random.normal(ks[5], (1, 64, h, d), jnp.bfloat16)
     (kq, kscale), (vq, vscale) = quantize_kv(kp), quantize_kv(vp)
@@ -275,25 +448,36 @@ def kernel_phase(cfg, seed: int) -> None:
         return kernels.linear(x, w, bias=bias, activation="silu",
                               policy=policy)
 
+    # a suffix's last chunk: 64 bucket rows from position 384, 18 real
+    pad_from, pad_to = 384, 402
+    pad_start = jnp.array([pad_from], jnp.int32)
+    pad_len = jnp.array([pad_to], jnp.int32)
+    every = (slice(None),)
+    # (fn, args, the output rows the engine keeps)
     cases = {
-        "paged decode": (paged, (q1, kp, vp, table, lengths - 1, lengths)),
+        "paged decode": (paged, (q1, kp, vp, dec_table, dec_index,
+                                 dec_lengths), (slice(0, b - idle),)),
         "pallas_prefill": (paged, (q64, kp, vp, table[:1], lengths[:1] - 64,
-                                   lengths[:1])),
+                                   lengths[:1]), every),
         "pallas_prefill int8": (paged, (q64, kq, vq, table[:1],
                                         lengths[:1] - 64, lengths[:1],
-                                        kscale, vscale)),
-        "linear bias+silu": (linear, (x, w, bias)),
+                                        kscale, vscale), every),
+        "pallas_prefill padded": (paged, (q64, kp, vp, table[:1], pad_start,
+                                          pad_len),
+                                  (0, slice(0, pad_to - pad_from))),
+        "linear bias+silu": (linear, (x, w, bias), every),
     }
-    for name, (fn, args) in cases.items():
+    for name, (fn, args, keep) in cases.items():
         step = jax.jit(fn, static_argnums=0)
-        rel = rel_diff(step(None, *args), step("backend=reference", *args))
+        rel = rel_diff(step(None, *args)[keep],
+                       step("backend=reference", *args)[keep])
         say(f"kernel {name}: max rel diff {rel:.6g} vs reference "
             f"(tolerance {KERNEL_RTOL})")
         check(rel <= KERNEL_RTOL,
               f"{name} differs from the reference backend: {rel:.6g}")
 
 
-def default_phase(seed: int) -> None:
+def default_phase(seed: int, compiles: CompileLog) -> None:
     import jax
 
     from repro.configs import get_config
@@ -304,7 +488,7 @@ def default_phase(seed: int) -> None:
         f"{cfg.attn.head_dim}, d_ff {cfg.d_ff}, vocab {cfg.vocab}, "
         f"bf16 params from seed {seed}")
     argv = SERVE_ARGV + ["--seed", str(seed)]
-    run, rec = serve_phase(argv)
+    run, rec = serve_phase(argv, compiles)
     sched = check_kernels(rec)
     for op, pick in sorted(sched.items()):
         say(f"schedule {op}: {json.dumps(pick, sort_keys=True)}")
@@ -401,6 +585,7 @@ def main(argv: list[str] | None = None) -> int:
     cache_dir = runtime.setup_compile_cache()
     import jax
 
+    compiles = CompileLog()
     devs = jax.devices()
     dev = devs[0]
     if dev.platform != "tpu":
@@ -409,15 +594,19 @@ def main(argv: list[str] | None = None) -> int:
         return 1
     say(f"device {dev.device_kind} x {len(devs)} (platform {dev.platform}), "
         f"jax {jax.__version__}")
-    say(f"compile cache {cache_dir}")
+    entries0 = cache_entries(cache_dir)
+    say(f"compile cache {cache_dir}: {entries0} entries")
     try:
         if args.four_chip:
             four_chip_phase(args.seed)
         else:
-            default_phase(args.seed)
+            default_phase(args.seed, compiles)
     except SmokeFailure as e:
         print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
         return 1
+    entries = cache_entries(cache_dir)
+    say(f"compile cache {cache_dir}: {entries} entries "
+        f"({entries - entries0:+d}); run compiles: {compiles.summary()}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devs)}}))

@@ -811,10 +811,11 @@ class PagedEngine:
     # -- copy-on-write / fork ----------------------------------------------
     def fork(self, slot: int, req: Request,
              shard: int | None = None) -> int | None:
-        """Fork a running request: the child shares *every* page of the
-        parent (one refcount bump per page — no copies); the next write
-        to the shared tail page copy-on-writes.  Returns the child slot,
-        or None when no slot (or, cross-shard, no page) is free.
+        """Fork a running request.  On the parent's shard the child
+        shares every page of the parent (one refcount bump per page — no
+        copies) and the next write to the shared tail page
+        copy-on-writes.  Returns the child slot, or None when no slot
+        (or, cross-shard, no page) is free.
 
         ``shard`` places the child on another shard (default: the
         parent's, or the request's pinned one).  A slot's pages all live
@@ -882,14 +883,15 @@ class PagedEngine:
                     return False
                 st.pages.extend(got)
             elif self.pool.refcount(st.pages[need]) > 1:
-                # the private copy lands on the slot's own shard
-                res = self.pool.cow(st.pages[need], st.shard)
+                # the private copy lands on the page's shard, which is
+                # the slot's own (fork broadcasts across shards)
+                res = self.pool.cow(st.pages[need])
                 if res is None:  # pool dry: make room, then retry the COW
                     got = self._alloc_for_decode(
                         1, exclude={slot}, shard=st.shard)
                     if got is not None:
                         self.pool.release(got)
-                        res = self.pool.cow(st.pages[need], st.shard)
+                        res = self.pool.cow(st.pages[need])
                 if res is None:
                     self._requeue_degraded(
                         slot, "COW failure with pool exhausted")

@@ -194,24 +194,22 @@ class PagePool:
                         args={"n": len(page_ids), "freed": len(freed)})
         return freed
 
-    def cow(self, page_id: int, shard: int | None = None) -> tuple[int, bool] | None:
+    def cow(self, page_id: int) -> tuple[int, bool] | None:
         """Copy-on-write: make ``page_id`` exclusively owned by the caller.
 
         Returns ``(page_id, False)`` when the caller already owns it
         exclusively (refcount 1 — no copy needed), ``(new_id, True)``
         when the page was shared (the caller must copy the device bytes
         ``new_id <- page_id`` and use ``new_id`` from now on; the old
-        reference is released), or ``None`` when the pool is dry.
-
-        ``shard`` places the private copy; the default keeps it on
-        ``page_id``'s own shard."""
+        reference is released), or ``None`` when the pool is dry.  The
+        private copy lands on ``page_id``'s own shard."""
         if self._ref[page_id] <= 0:
             raise ValueError(f"cow of unreferenced page {page_id}")
         if self._ref[page_id] == 1:
             return page_id, False
         if faults.fires("pool.cow") is not None:
             return None  # injected COW failure: same signal as a dry pool
-        granted = self.alloc(1, self.shard_of(page_id) if shard is None else shard)
+        granted = self.alloc(1, self.shard_of(page_id))
         if granted is None:
             return None
         self.release([page_id])

@@ -270,6 +270,16 @@ class ServeLoop:
                                     req.error)
             self._n_failed_seen = len(eng.failed)
 
+    def _fail_live_locked(self, reason: str) -> None:
+        """Release every live slot's pages, drop the slot and finish its
+        request FAILED with ``reason``."""
+        eng = self.engine
+        for slot, st in list(eng.slots.items()):
+            eng.pool.release(st.pages)
+            del eng.slots[slot]
+            self._finish_locked(self._by_rid[st.req.rid], Lifecycle.FAILED,
+                                reason)
+
     def _done_serving(self) -> bool:
         return self._closing and not self._queue \
             and not self.engine.slots and not self.engine._requeue
@@ -289,11 +299,7 @@ class ServeLoop:
                 for sreq in self._queue:
                     self._finish_locked(sreq, Lifecycle.FAILED, repr(e))
                 self._queue.clear()
-                for slot, st in list(self.engine.slots.items()):
-                    self.engine.pool.release(st.pages)
-                    del self.engine.slots[slot]
-                    self._finish_locked(self._by_rid[st.req.rid],
-                                        Lifecycle.FAILED, repr(e))
+                self._fail_live_locked(repr(e))
                 self._work.notify_all()
 
     def _prefill_worker(self) -> None:
@@ -388,11 +394,7 @@ class ServeLoop:
                     return
                 if self._abort:
                     # non-draining shutdown: fail live slots, free pages
-                    for slot, st in list(eng.slots.items()):
-                        eng.pool.release(st.pages)
-                        del eng.slots[slot]
-                        self._finish_locked(self._by_rid[st.req.rid],
-                                            Lifecycle.FAILED, "shutdown")
+                    self._fail_live_locked("shutdown")
                     self._work.notify_all()
                     return
                 if not eng.slots:

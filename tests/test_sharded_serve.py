@@ -153,12 +153,12 @@ def test_pool_single_shard_degenerate_grant_order():
     p.check()
 
 
-def test_pool_cross_shard_cow_places_copy():
+def test_pool_cow_copy_stays_on_page_shard():
     p = PagePool(10, 8, num_shards=2)
-    (pid,) = p.alloc(1, 0)
+    (pid,) = p.alloc(1, 1)
     p.share([pid])  # two holders -> a write must copy
-    new_pid, copied = p.cow(pid, shard=1)
-    assert copied and p.shard_of(new_pid) == 1
+    new_pid, copied = p.cow(pid)
+    assert copied and new_pid != pid and p.shard_of(new_pid) == 1
     assert p.refcount(pid) == 1  # the other holder keeps the original
     p.check()
 
