@@ -19,7 +19,8 @@ interpret-mode timings for the forced-pallas kernel rows):
   the derived column reports the overhead vs. the unguarded row and
   asserts it stays under 5%.
 * ``kernel_serve_trace_overhead`` — the bf16 decode workload with the
-  ``repro.obs`` tracing recorder armed (engine.step/engine.decode spans
+  ``repro.obs`` tracing recorder armed (the engine.step, decode.prepare,
+  engine.decode and engine.sample spans, each a profiler annotation too,
   plus pool/prefix instants per step); the derived column reports the
   overhead vs. the untraced row and asserts it stays under 5%.
 * ``kernel_serve_prefill_cold``   — admission latency for a cold
@@ -148,9 +149,9 @@ def run(only: str | None = None) -> list[str]:
                 f"{overhead:+.1f}% vs unguarded (gate <5%)"
             )
         if want("kernel_serve_trace_overhead"):
-            # same workload with the obs recorder armed: per step, two
-            # span dict appends (engine.step + engine.decode) and the
-            # release instants — the tracing-on price of the PR-9 layer
+            # same workload with the obs recorder armed: per step, four
+            # spans (engine.step and its decode phases), each also a
+            # profiler annotation, and the release instants
             from repro.obs import trace as obs_trace
 
             with obs_trace.tracing(max_events=1 << 16):

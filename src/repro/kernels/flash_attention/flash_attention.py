@@ -153,6 +153,7 @@ def flash_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
     return out
@@ -273,6 +274,7 @@ def flash_attention_bwd_dq(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_attention_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -317,5 +319,6 @@ def flash_attention_bwd_dkv(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="flash_attention_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)

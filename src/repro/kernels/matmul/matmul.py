@@ -120,6 +120,7 @@ def _mcast_call(a, b, *, bn, bk, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name="matmul_mcast",
         interpret=interpret,
     )(a, b)
 
@@ -219,6 +220,7 @@ def matmul_mcast_tiled(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="matmul_mcast_tiled",
         interpret=interpret,
     )(*operands)
     return out[:m, :n] if (mp, np_) != (m, n) else out
@@ -271,6 +273,7 @@ def _unicast_call(a, b, *, bm, bn, bk, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="matmul_unicast",
         interpret=interpret,
     )(a, b)
 

@@ -155,6 +155,7 @@ def paged_attention_decode(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="paged_decode",
         interpret=interpret,
     )(
         block_table.astype(jnp.int32), start.astype(jnp.int32),
@@ -313,6 +314,7 @@ def paged_attention_prefill(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
+        name="pallas_prefill",
         interpret=interpret,
     )(
         block_table.astype(jnp.int32), start.astype(jnp.int32),

@@ -71,6 +71,7 @@ def rglru_scan(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="rglru_scan",
         interpret=interpret,
     )(a, b)
 
@@ -124,5 +125,6 @@ def rglru_scan_bwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="rglru_scan_bwd",
         interpret=interpret,
     )(a, h_prev, dh)

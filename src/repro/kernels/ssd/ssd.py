@@ -113,6 +113,7 @@ def ssd_scan(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="ssd_scan",
         interpret=interpret,
     )(xdt, b, c, lcum_chunk)
 
@@ -261,5 +262,6 @@ def ssd_scan_bwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        name="ssd_scan_bwd",
         interpret=interpret,
     )(xdt, b, c, lcum_chunk, states, dy)

@@ -4,10 +4,10 @@ Call sites follow the ``faults.fires`` pattern — one module-global read
 decides everything:
 
     rec = trace.active()
-    if rec is not None:
-        t0 = rec.now()
-        ...
-        rec.complete("engine.decode", t0, cat="kernel", args={...})
+    sp = rec.begin("engine.decode", cat="kernel") if rec is not None else None
+    ...
+    if sp is not None:
+        sp.end(args={...})
 
 When no recorder is armed ``active()`` is a single global load returning
 ``None``: zero events, zero allocations, no locks taken.  When armed,
@@ -20,12 +20,21 @@ Events are stored directly in Chrome/Perfetto trace-event form
 recorder's arm time) so export is a plain JSON dump — see
 :mod:`repro.obs.export`.
 
+A span opened with :meth:`Recorder.begin` is also a
+``jax.profiler.TraceAnnotation`` of the same name from begin to
+:meth:`Span.end`: while a profiler trace runs, it lands on the host
+plane of the ``.xplane.pb``, on the same clock as the device's
+operations.  Spans recorded after the fact (``rec.complete(name, t0,
+t1)``) are the recorder's alone: the waits of a request, which no
+thread spends.
+
 Timestamps use ``time.monotonic`` by default, the same clock
 ``serve.server.ServeLoop`` and ``serve.metrics`` use, so span endpoints
 and metrics histograms share a timebase.  Instrumentation that already
-holds a clock value passes it explicitly (``rec.complete(name, t0, t1)``)
-instead of re-reading the clock, keeping trace spans numerically equal
-to the metrics they mirror.
+holds a clock value passes it explicitly (``rec.begin(name, ts=t0)``,
+``sp.end(t1)``, ``rec.complete(name, t0, t1)``) instead of re-reading
+the clock, keeping trace spans numerically equal to the metrics
+they mirror.
 """
 from __future__ import annotations
 
@@ -36,7 +45,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Any, Callable, Optional
 
-__all__ = ["Recorder", "active", "span", "start", "stop", "tracing"]
+__all__ = ["Recorder", "Span", "active", "span", "start", "stop", "tracing"]
 
 _ACTIVE: Optional["Recorder"] = None  # the armed recorder; None == disabled
 
@@ -46,6 +55,27 @@ DEFAULT_MAX_EVENTS = 1 << 20
 def active() -> Optional["Recorder"]:
     """The armed :class:`Recorder`, or ``None`` (the hot-path fast exit)."""
     return _ACTIVE
+
+
+class Span:
+    """An open span (:meth:`Recorder.begin`), closed by :meth:`end`."""
+
+    __slots__ = ("rec", "name", "cat", "t0", "args", "_annotation")
+
+    def __init__(self, rec, name, cat, t0, args, annotation):
+        self.rec, self.name, self.cat = rec, name, cat
+        self.t0, self.args, self._annotation = t0, args, annotation
+
+    def end(self, ts: Optional[float] = None, *,
+            args: Optional[dict] = None) -> None:
+        """Close at ``ts`` (clock seconds; now when None) and record the
+        span (ph="X"); ``args`` join those given at begin."""
+        t1 = self.rec.clock() if ts is None else ts
+        self._annotation.__exit__(None, None, None)
+        if args and self.args:
+            args = {**self.args, **args}
+        self.rec.complete(self.name, self.t0, t1, cat=self.cat,
+                          args=args or self.args)
 
 
 class Recorder:
@@ -68,6 +98,10 @@ class Recorder:
         self._mu = threading.Lock()
         self._events: deque = deque(maxlen=self.max_events)
         self._named_tids: set = set()
+        # imported when a recorder is made: obs stays jax-free at import
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # -- time ----------------------------------------------------------
 
@@ -104,6 +138,22 @@ class Recorder:
             "name": name, "cat": cat, "ph": ph, "ts": self.to_us(t),
             "pid": self.pid, "tid": threading.get_ident(),
         }
+
+    def begin(
+        self,
+        name: str,
+        *,
+        cat: str = "",
+        args: Optional[dict] = None,
+        ts: Optional[float] = None,
+    ) -> Span:
+        """Open a span at ``ts`` (clock seconds; now when None), entering a
+        profiler annotation of the same name.  End it on the same thread,
+        innermost first."""
+        annotation = self._annotation(name)
+        annotation.__enter__()
+        t0 = self.clock() if ts is None else ts
+        return Span(self, name, cat, t0, args, annotation)
 
     def complete(
         self,
@@ -240,8 +290,8 @@ def span(name: str, *, cat: str = "", args: Optional[dict] = None):
     if rec is None:
         yield None
         return
-    t0 = rec.clock()
+    sp = rec.begin(name, cat=cat, args=args)
     try:
         yield rec
     finally:
-        rec.complete(name, t0, cat=cat, args=args)
+        sp.end()

@@ -499,8 +499,11 @@ class PagedEngine:
             return out
 
         self.kernel_calls[name] += 1
+        # the launch only: dispatch is asynchronous, so the span ends once
+        # the step is enqueued (the host waits for it in engine.sample)
         rec = trace.active()
-        t0 = rec.now() if rec is not None else 0.0
+        sp = rec.begin(f"engine.{name}", cat="kernel") \
+            if rec is not None else None
         if not self.kernel_fallback:
             out = primary(*args)
             fell_back = False
@@ -511,9 +514,8 @@ class PagedEngine:
             )
             if fell_back:
                 self.n_fallback += 1
-        if rec is not None:
-            rec.complete(f"engine.{name}", t0, cat="kernel",
-                         args={"fallback": fell_back})
+        if sp is not None:
+            sp.end(args={"fallback": fell_back})
         return out
 
     # -- admission ----------------------------------------------------------
@@ -529,10 +531,9 @@ class PagedEngine:
         rec = trace.active()
         if rec is None:
             return self._admit_impl(req)
-        t0 = rec.now()
+        sp = rec.begin("engine.admit", cat="engine")
         res = self._admit_impl(req)
-        rec.complete("engine.admit", t0, cat="engine",
-                     args={"rid": req.rid, "ok": res is True})
+        sp.end(args={"rid": req.rid, "ok": res is True})
         return res
 
     def _admit_impl(self, req: Request) -> bool | Rejected:
@@ -675,7 +676,7 @@ class PagedEngine:
         self.slots[slot] = _Slot(
             req=req, pages=pages, length=len(tokens),
             last_tok=(req.out[-1] if replay
-                      else int(self.sampler.select(logits)[0, -1])),
+                      else int(self._sample(logits)[0, -1])),
             admit_seq=self._admit_seq, shard=shard,
         )
         self._admit_seq += 1
@@ -912,11 +913,21 @@ class PagedEngine:
         rec = trace.active()
         if rec is None:
             return self._step_impl()
-        t0 = rec.now()
+        sp = rec.begin("engine.step", cat="engine")
         n_slots = len(self.slots)
         out = self._step_impl()
-        rec.complete("engine.step", t0, cat="engine",
-                     args={"n_slots": n_slots, "finished": len(out)})
+        sp.end(args={"n_slots": n_slots, "finished": len(out)})
+        return out
+
+    def _sample(self, logits) -> np.ndarray:
+        """The sampler's choice, pulled to the host: where the host waits
+        for the step the device runs (span ``engine.sample``)."""
+        rec = trace.active()
+        if rec is None:
+            return self.sampler.select(logits)
+        sp = rec.begin("engine.sample", cat="engine")
+        out = self.sampler.select(logits)
+        sp.end()
         return out
 
     def _step_impl(self) -> list[Request]:
@@ -932,10 +943,17 @@ class PagedEngine:
                         for st in self.slots.values()) - 1)
             if k >= 1:
                 return self._step_spec(k)
+        # decode.prepare: page faults and COW, then the step's inputs
+        # built on the host and uploaded
+        rec = trace.active()
+        prep = rec.begin("decode.prepare", cat="engine") \
+            if rec is not None else None
         for slot in sorted(self.slots, key=lambda s: self.slots[s].admit_seq):
             if slot in self.slots:  # a page fault may preempt later slots
                 self._ensure_writable(slot)
         if not self.slots:
+            if prep is not None:
+                prep.end()
             return []
         toks = np.zeros((self.max_batch, 1), np.int32)
         index = np.zeros(self.max_batch, np.int32)
@@ -946,12 +964,13 @@ class PagedEngine:
             index[slot] = st.length
             lengths[slot] = st.length + 1
             table[slot] = self._table_row(st.pages)
+        args = (jnp.asarray(toks), jnp.asarray(index), jnp.asarray(table),
+                jnp.asarray(lengths))
+        if prep is not None:
+            prep.end()
         logits, self.caches = self._dispatch(
-            "decode",
-            self.params, self.caches, jnp.asarray(toks), jnp.asarray(index),
-            jnp.asarray(table), jnp.asarray(lengths),
-        )
-        nxt = self.sampler.select(logits)[:, -1]
+            "decode", self.params, self.caches, *args)
+        nxt = self._sample(logits)[:, -1]
         finished = []
         for slot, st in list(self.slots.items()):
             st.length += 1
@@ -1013,7 +1032,7 @@ class PagedEngine:
             self.params, self.caches, jnp.asarray(toks), jnp.asarray(index),
             jnp.asarray(table), jnp.asarray(lengths),
         )
-        target = self.sampler.select(logits)        # (max_batch, k+1)
+        target = self._sample(logits)               # (max_batch, k+1)
         accepted = self.sampler.verify(drafts, target)
         finished = []
         new_lengths: dict[int, int] = {}

@@ -170,6 +170,14 @@ class ServeLoop:
         self._warm_decode = False
         self._warm_verify = False
         self._error: BaseException | None = None  # first worker failure
+        # kept while a trace recorder is armed, for request.admit_wait and
+        # decode.wait: the last admission's end and whether it filled every
+        # slot, the tick that freed a slot of a full batch, and the rids
+        # admitted while the decode worker waits for the lock
+        self._admit_end_t = 0.0
+        self._admit_filled = False
+        self._freed_t = 0.0
+        self._wait_admits: list[int] | None = None
         self._threads = [
             threading.Thread(target=self._guarded, args=(worker,),
                              name=name, daemon=True)
@@ -339,13 +347,8 @@ class ServeLoop:
                     self._flush_tokens_locked(head, t_done)
                     rec = trace.active()
                     if rec is not None:
-                        rec.complete("request.queue_wait", head.arrival_t,
-                                     t_start, cat="serve",
-                                     args={"rid": head.rid})
-                        rec.complete("request.prefill", t_start, t_done,
-                                     cat="serve",
-                                     args={"rid": head.rid,
-                                           "overlapped": overlapped})
+                        self._trace_admission(rec, head, t_start, t_done,
+                                              overlapped)
                     self._work.notify_all()
                     continue
                 # typed backpressure: the head stays at the front (FIFO —
@@ -385,10 +388,35 @@ class ServeLoop:
                          or not eng.slots),
                     timeout=self._retry_s)
 
+    def _trace_admission(self, rec, head: ServedRequest, t_start: float,
+                         t_done: float, overlapped: bool) -> None:
+        """The request spans of one admission.  ``request.admit_wait`` is
+        the part of ``request.queue_wait`` after the request could first
+        have been admitted, as the queue head with a slot free: the later
+        of its arrival, the previous admission's end and, where that
+        admission filled every slot, the tick that freed one."""
+        ready = max(head.arrival_t, self._admit_end_t)
+        if self._admit_filled and self._freed_t > ready:
+            ready = self._freed_t
+        rec.complete("request.queue_wait", head.arrival_t, t_start,
+                     cat="serve", args={"rid": head.rid})
+        rec.complete("request.admit_wait", min(ready, t_start), t_start,
+                     cat="serve", args={"rid": head.rid})
+        rec.complete("request.prefill", t_start, t_done, cat="serve",
+                     args={"rid": head.rid, "overlapped": overlapped})
+        self._admit_end_t = t_done
+        self._admit_filled = len(self.engine.slots) >= self.max_slots
+        if self._wait_admits is not None:
+            self._wait_admits.append(head.rid)
+
     def _decode_worker(self) -> None:
         eng = self.engine
+        wait = None  # decode.wait: from releasing the lock to holding it again
         while True:
             with self._work:
+                if wait is not None:
+                    wait.end(args={"admitted": self._wait_admits})
+                    wait = self._wait_admits = None
                 if self._done_serving():
                     self._work.notify_all()
                     return
@@ -402,15 +430,19 @@ class ServeLoop:
                     continue
                 n_live = len(eng.slots)
                 rec = trace.active()
-                t_tick = self.clock() if rec is not None else 0.0
+                tick = rec.begin("decode.tick", cat="serve", ts=self.clock()) \
+                    if rec is not None else None
                 finished = eng.step()
                 t = self.clock()
+                if tick is not None:
+                    tick.end(t, args={"n_slots": n_live,
+                                      "finished": len(finished)})
+                    if finished and n_live >= self.max_slots:
+                        self._freed_t = t
+                # decode.emit: the step's tokens handed on, requests closed
+                emit = rec.begin("decode.emit", cat="serve", ts=t) \
+                    if rec is not None else None
                 self.metrics.record_tick(n_live)
-                if rec is not None:
-                    rec.complete("decode.tick", t_tick, t, cat="serve",
-                                 args={"n_slots": n_live,
-                                       "finished": len(finished)})
-                    rec.counter("live_slots", len(eng.slots), ts=t)
                 for req in [st.req for st in eng.slots.values()] + finished:
                     self._flush_tokens_locked(self._by_rid[req.rid], t)
                 for req in finished:
@@ -419,6 +451,11 @@ class ServeLoop:
                 self._sweep_engine_locked()
                 self._release_gen += 1
                 self._work.notify_all()
+                if emit is not None:
+                    emit.end()
+                    if eng.slots:
+                        self._wait_admits = []
+                        wait = rec.begin("decode.wait", cat="serve")
             # outside the lock: one scheduler slice so a pending
             # admission (or submit) can interleave between ticks
             time.sleep(0)
@@ -460,7 +497,8 @@ class ServeLoop:
         eng = self.engine
         n = 0
         rec = trace.active()
-        t0 = self.clock() if rec is not None else 0.0
+        sp = rec.begin("compile.warmup", cat="serve") if rec is not None \
+            else None
         with self._work:
             for ln in prompt_lens:
                 b = bucket_len(ln, eng.prompt_bucket)
@@ -517,9 +555,8 @@ class ServeLoop:
                 for _ in range(eng.spec.warmup(buckets, eng.spec_k)):
                     self.metrics.record_bucket_compile()
                     n += 1
-        if rec is not None and n:
-            rec.complete("compile.warmup", t0, self.clock(), cat="serve",
-                         args={"programs": n})
+        if sp is not None:
+            sp.end(args={"programs": n})
         return n
 
     def warmup_for_trace(self, trace) -> int:

@@ -147,20 +147,18 @@ class ModelDraft(DraftModel):
         self._mask_rows = jax.jit(lm.mask_cache_rows_after)
 
     # ------------------------------------------------------------------
-    def _span(self, name, t0, rec, **args):
-        if rec is not None:
-            rec.complete(f"engine.{name}", t0, cat="kernel", args=args)
-
     def _resync(self, slot: int, view: SlotView) -> None:
         ctx = list(view.tokens[:view.length])
         toks = pad_to_bucket(ctx, self._bucket)
         rec = trace.active()
-        t0 = rec.now() if rec is not None else 0.0
+        sp = rec.begin("engine.draft_prefill", cat="kernel") \
+            if rec is not None else None
         self.kernel_calls["draft_prefill"] += 1
         _, caches_one = self._prefill_one(
             self.params, jnp.asarray(toks), jnp.int32(len(ctx) - 1),
             jnp.int32(len(ctx)))
-        self._span("draft_prefill", t0, rec, slot=slot, len=len(ctx))
+        if sp is not None:
+            sp.end(args={"slot": slot, "len": len(ctx)})
         self.caches = jax.tree.map(
             lambda full, one: full.at[:, slot:slot + 1].set(one)
             if full.ndim >= 2 else full,
@@ -180,12 +178,14 @@ class ModelDraft(DraftModel):
         drafts = np.zeros((self.max_slots, k), np.int32)
         rec = trace.active()
         for j in range(k):
-            t0 = rec.now() if rec is not None else 0.0
+            sp = rec.begin("engine.draft_decode", cat="kernel") \
+                if rec is not None else None
             self.kernel_calls["draft_decode"] += 1
             logits, self.caches = self._decode(
                 self.params, self.caches,
                 jnp.asarray(toks)[:, None], jnp.asarray(idx))
-            self._span("draft_decode", t0, rec, step=j, n_slots=len(views))
+            if sp is not None:
+                sp.end(args={"step": j, "n_slots": len(views)})
             toks = self.sampler.select(logits)[:, -1]
             drafts[:, j] = toks
             idx += 1

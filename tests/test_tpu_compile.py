@@ -74,6 +74,13 @@ def _custom_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _named(compiled, name: str) -> list[str]:
+    """The instructions named after kernel ``name`` (``%name.1 = ...``):
+    the name the profiler's trace shows for the kernel's operation."""
+    return re.findall(rf"%{name}(?:\.\d+)? = \S+ custom-call\(",
+                      compiled.as_text())
+
+
 def _paged_args(cfg, b, s, num_pages, sharding, *, int8=False):
     kvh, h, d = cfg.attn.n_kv_heads, cfg.attn.n_heads, cfg.attn.head_dim
     pdt = jnp.int8 if int8 else jnp.bfloat16
@@ -93,7 +100,9 @@ def _paged_args(cfg, b, s, num_pages, sharding, *, int8=False):
 def test_paged_decode_kernel_compiles(cfg, one_chip, on_tpu):
     args = _paged_args(cfg, 8, 1, 1025, one_chip)
     f = jax.jit(lambda *a: kernels.op("paged_attention")(*a, policy="pallas"))
-    assert _custom_calls(f.lower(*args).compile()) == 1
+    compiled = f.lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    assert len(_named(compiled, "paged_decode")) == 1
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
@@ -102,7 +111,9 @@ def test_pallas_prefill_kernel_compiles(cfg, one_chip, on_tpu, s, int8):
     args = _paged_args(cfg, 1, s, 1025, one_chip, int8=int8)
     f = jax.jit(
         lambda *a: kernels.op("paged_attention")(*a, policy="pallas_prefill"))
-    assert _custom_calls(f.lower(*args).compile()) == 1
+    compiled = f.lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    assert len(_named(compiled, "pallas_prefill")) == 1
 
 
 @pytest.mark.parametrize("m,k,n,bias,act", [

@@ -3,7 +3,9 @@ thread metadata, zero-cost disabled path), export schema validation,
 span nesting against the engine/loop worker structure, and the offline
 analyzer's exact cross-checks against the live engine/pool/prefix
 counters and ``dist/mcast.bytes_model``."""
+import glob
 import json
+import os
 import tracemalloc
 
 import jax
@@ -107,6 +109,19 @@ def test_counter_track_is_time_ordered():
     ts = [e["ts"] for e in samples]
     assert ts == sorted(ts)  # monotone clock -> monotone track
     assert [e["args"]["value"] for e in samples] == [1, 2, 3, 5, 8]
+
+
+def test_begin_end_span_joins_args_and_takes_given_times():
+    rec = obs_trace.Recorder()
+    t0 = rec.now()
+    sp = rec.begin("work", cat="c", args={"a": 1}, ts=t0)
+    sp.end(t0 + 0.25, args={"b": 2})
+    rec.begin("bare").end()
+    ev, bare = [e for e in rec.events() if e["ph"] == "X"]
+    assert ev["name"] == "work" and ev["cat"] == "c"
+    assert ev["args"] == {"a": 1, "b": 2}
+    assert ev["dur"] == pytest.approx(0.25e6)
+    assert bare["dur"] >= 0 and "args" not in bare
 
 
 def test_start_twice_raises_and_tracing_scopes():
@@ -311,10 +326,155 @@ def test_loop_trace_ttft_decomposition_matches_metrics(small):
     # reproduce the metrics histograms (same values, same histogram)
     assert abs(report["ttft_decomposed_p50_ms"] - snap["ttft_p50_ms"]) < 1.0
     assert abs(report["queue_wait_p50_ms"] - snap["queue_wait_p50_ms"]) < 1.0
-    # live_slots counter track exists and never exceeds max_slots
-    slots = [e["args"]["value"] for e in events
-             if e["ph"] == "C" and e["name"] == "live_slots"]
+    # every tick names the slots it decoded, never more than max_slots
+    slots = [t["args"]["n_slots"] for t in ticks]
     assert slots and max(slots) <= 3
+
+
+# ---------------------------------------------------------------------------
+# the serve loop's phases and lock waits, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+DECODE_PHASES = ("decode.prepare", "engine.decode", "engine.sample")
+
+
+def _staggered_loop(small, tmp_path=None):
+    """Six requests submitted at once to two slots, each with its own
+    output length, so slots free one at a time while the other decodes
+    and most admissions land between two decode steps.  With
+    ``tmp_path`` the run is also under ``jax.profiler``; returns the
+    recorder's events and the profiler's log directory."""
+    cfg, params = small
+    eng = PagedEngine(cfg, params, config=ServeConfig(
+        max_slots=2, cache_len=64, page_size=16))
+    loop = ServeLoop(eng)
+    reqs = _mk_requests(cfg, n=7, max_new=3)
+    loop.submit(reqs[0].prompt, 3).result(timeout=120)  # compile first
+    if tmp_path is not None:
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.tracing() as rec:
+            handles = [loop.submit(r.prompt, n) for r, n in
+                       zip(reqs[1:], (3, 6, 4, 7, 5, 8))]
+            loop.close(drain=True)
+    finally:
+        if tmp_path is not None:
+            jax.profiler.stop_trace()
+    assert {h.state for h in handles} == {Lifecycle.DRAINED}
+    return rec.events()
+
+
+def _host_plane(log_dir, names) -> dict:
+    """{name: [(line, start s, end s)]} of the host plane's events."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    out = {n: [] for n in names}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name in out:
+                    t = e.start_ns * 1e-9
+                    out[e.name].append((i, t, t + e.duration_ns * 1e-9))
+    return {n: sorted(v, key=lambda x: x[1]) for n, v in out.items()}
+
+
+def test_decode_phases_reach_the_profiler_nested_and_timed(small, tmp_path):
+    events = _staggered_loop(small, tmp_path)
+    names = ("decode.tick", "engine.step", "engine.admit", "decode.emit",
+             "decode.wait") + DECODE_PHASES
+    host = _host_plane(str(tmp_path), names)
+    ticks = host["decode.tick"]
+    assert ticks
+    for name in names:
+        spans = sorted(_spans(events, name), key=lambda e: e["ts"])
+        assert len(host[name]) == len(spans) > 0, name
+        # the same span on both clocks: equal durations within 1 ms
+        for (_, a, b), ev in zip(host[name], spans):
+            assert abs((b - a) - ev["dur"] * 1e-6) < 1e-3, name
+
+    def holders(outer, inner):
+        return [o for o in host[outer]
+                if o[0] == inner[0] and o[1] <= inner[1] and inner[2] <= o[2]]
+
+    # each phase sits inside one decode tick, on the tick's own thread;
+    # the other samples are the admissions' first tokens
+    for name in ("engine.step",) + DECODE_PHASES:
+        in_ticks = [e for e in host[name] if holders("decode.tick", e)]
+        assert len(in_ticks) == len(ticks), name
+        for e in host[name]:
+            assert len(holders("decode.tick", e)
+                       + holders("engine.admit", e)) == 1, name
+    # and in the recorder, each tick holds one of each phase, in order
+    for t in _spans(events, "decode.tick"):
+        inside = sorted((e for e in events if e["ph"] == "X"
+                         and e["name"] in DECODE_PHASES and _contained(e, t)),
+                        key=lambda e: e["ts"])
+        assert [e["name"] for e in inside] == list(DECODE_PHASES)
+        assert all(e["cat"] == "engine" for e in inside
+                   if e["name"] != "engine.decode")
+
+
+def test_admit_wait_lies_inside_queue_wait(small):
+    events = _staggered_loop(small)
+    queue = {e["args"]["rid"]: e for e in _spans(events, "request.queue_wait")}
+    admit = {e["args"]["rid"]: e for e in _spans(events, "request.admit_wait")}
+    prefill = {e["args"]["rid"]: e for e in _spans(events, "request.prefill")}
+    assert len(admit) == len(queue) == len(prefill) == 6
+    for rid, a in admit.items():
+        assert _contained(a, queue[rid])
+        # both end where the admission starts
+        assert a["ts"] + a["dur"] == pytest.approx(prefill[rid]["ts"], abs=1e-3)
+    # a request queued behind a full batch becomes ready when a tick
+    # frees a slot: its admit wait starts at that tick's end
+    tick_ends = [t["ts"] + t["dur"] for t in _spans(events, "decode.tick")
+                 if t["args"]["finished"]]
+    behind = [a for rid, a in admit.items()
+              if any(abs(a["ts"] - t) < 1e-3 for t in tick_ends)]
+    assert behind
+    for a in behind:
+        assert a["dur"] < queue[a["args"]["rid"]]["dur"]
+
+
+def test_decode_wait_names_the_admissions_it_waited_for(small):
+    events = _staggered_loop(small)
+    waits = _spans(events, "decode.wait")
+    prefill = {e["args"]["rid"]: e for e in _spans(events, "request.prefill")}
+    assert waits
+    named = 0
+    for w in waits:
+        rids = w["args"]["admitted"]
+        named += len(rids)
+        for rid in rids:
+            assert _contained(prefill[rid], w)
+    for rid, p in prefill.items():
+        for w in waits:
+            if _contained(p, w):
+                assert rid in w["args"]["admitted"]
+    assert named  # admissions landed between two decode steps
+
+
+def test_loop_tracing_off_allocates_nothing_in_obs(small):
+    cfg, params = small
+    eng = PagedEngine(cfg, params, config=ServeConfig(
+        max_slots=2, cache_len=64, page_size=16))
+    loop = ServeLoop(eng)
+    reqs = _mk_requests(cfg, n=3, max_new=4)
+    loop.submit(reqs[0].prompt, 3).result(timeout=120)  # compile first
+    tracemalloc.start()
+    try:
+        for r in reqs[1:]:
+            loop.submit(r.prompt, r.max_new)
+        loop.close(drain=True)
+        snap = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    ours = snap.filter_traces(
+        [tracemalloc.Filter(True, obs_trace.__file__)]).statistics("lineno")
+    assert ours == []
 
 
 # ---------------------------------------------------------------------------
