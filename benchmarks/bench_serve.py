@@ -283,10 +283,10 @@ def run(only: str | None = None) -> list[str]:
         num_pages = 1 + PF_B * PF_PAGES
         q = jax.random.normal(ks[0], (PF_B, PF_S, PF_H, PF_D), jnp.float32)
         kp = jax.random.normal(
-            ks[1], (PF_KVH, num_pages, PAGE_SIZE, PF_D), jnp.float32
+            ks[1], (1, PF_KVH, num_pages, PAGE_SIZE, PF_D), jnp.float32
         )
         vp = jax.random.normal(
-            ks[2], (PF_KVH, num_pages, PAGE_SIZE, PF_D), jnp.float32
+            ks[2], (1, PF_KVH, num_pages, PAGE_SIZE, PF_D), jnp.float32
         )
         table = jnp.arange(1, 1 + PF_B * PF_PAGES, dtype=jnp.int32) \
             .reshape(PF_B, PF_PAGES)
@@ -298,9 +298,9 @@ def run(only: str | None = None) -> list[str]:
             (PF_B, PF_S, PF_H, PF_KVH, PF_PAGES, PAGE_SIZE, PF_D, 0),
             jnp.float32, policy="pallas",
         )
-        pallas_fn = lambda: paged(q, kp, vp, table, start, lengths,  # noqa: E731
+        pallas_fn = lambda: paged(q, kp, vp, table, start, lengths, 0,  # noqa: E731
                                   policy="pallas")
-        ref_fn = lambda: paged(q, kp, vp, table, start, lengths,  # noqa: E731
+        ref_fn = lambda: paged(q, kp, vp, table, start, lengths, 0,  # noqa: E731
                                policy="reference")
         for fn in (pallas_fn, ref_fn):
             fn().block_until_ready()  # compile

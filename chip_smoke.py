@@ -419,14 +419,18 @@ def kernel_phase(cfg, seed: int) -> None:
     import jax.numpy as jnp
 
     from repro import kernels
+    from repro.nn.attention import packed_heads
     from repro.nn.kvquant import quantize_kv
 
     kvh, h, d = cfg.attn.n_kv_heads, cfg.attn.n_heads, cfg.attn.head_dim
     b, width, ps, idle = 8, 128, 16, 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     n_pages = b * width + 1
-    kp = jax.random.normal(ks[0], (kvh, n_pages, ps, d), jnp.bfloat16)
-    vp = jax.random.normal(ks[1], (kvh, n_pages, ps, d), jnp.bfloat16)
+    # one layer's pool, laid out as the engine's (lane-packed heads) and
+    # read as layer 0 of a stack of one
+    pack = packed_heads(kvh, d)
+    kp, vp = (jax.random.normal(k, (1, kvh // pack, n_pages, ps, pack * d),
+                                jnp.bfloat16) for k in ks[:2])
     table = (jax.random.permutation(ks[2], b * width) + 1).reshape(b, width)
     table = table.astype(jnp.int32)
     lengths = jax.random.randint(ks[3], (b,), 65, width * ps + 1, jnp.int32)
@@ -436,7 +440,11 @@ def kernel_phase(cfg, seed: int) -> None:
     dec_index = jnp.maximum(dec_lengths - 1, 0)
     q1 = jax.random.normal(ks[4], (b, 1, h, d), jnp.bfloat16)
     q64 = jax.random.normal(ks[5], (1, 64, h, d), jnp.bfloat16)
-    (kq, kscale), (vq, vscale) = quantize_kv(kp), quantize_kv(vp)
+    # int8 pools keep one head per row
+    kp8, vp8 = (a.reshape(1, kvh // pack, n_pages, ps, pack, d)
+                .transpose(0, 1, 4, 2, 3, 5).reshape(1, kvh, n_pages, ps, d)
+                for a in (kp, vp))
+    (kq, kscale), (vq, vscale) = quantize_kv(kp8), quantize_kv(vp8)
     x = jax.random.normal(ks[6], (64, cfg.d_model), jnp.bfloat16)
     w = (0.02 * jax.random.normal(ks[7], (cfg.d_model, cfg.d_ff))
          ).astype(jnp.bfloat16)
@@ -456,14 +464,14 @@ def kernel_phase(cfg, seed: int) -> None:
     # (fn, args, the output rows the engine keeps)
     cases = {
         "paged decode": (paged, (q1, kp, vp, dec_table, dec_index,
-                                 dec_lengths), (slice(0, b - idle),)),
+                                 dec_lengths, 0), (slice(0, b - idle),)),
         "pallas_prefill": (paged, (q64, kp, vp, table[:1], lengths[:1] - 64,
-                                   lengths[:1]), every),
+                                   lengths[:1], 0), every),
         "pallas_prefill int8": (paged, (q64, kq, vq, table[:1],
-                                        lengths[:1] - 64, lengths[:1],
+                                        lengths[:1] - 64, lengths[:1], 0,
                                         kscale, vscale), every),
         "pallas_prefill padded": (paged, (q64, kp, vp, table[:1], pad_start,
-                                          pad_len),
+                                          pad_len, 0),
                                   (0, slice(0, pad_to - pad_from))),
         "linear bias+silu": (linear, (x, w, bias), every),
     }

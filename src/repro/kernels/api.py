@@ -13,6 +13,8 @@ Registry layout (one :class:`KernelOp` per family)::
 
     matmul           mcast | tiled | unicast   (pallas)  + reference
     flash_attention  pallas                              + reference
+    paged_attention  pallas | pallas_prefill   (pallas)  + reference
+    page_write       pallas                              + reference
     ssd              pallas                              + reference
     rglru            pallas                              + reference
 
@@ -91,10 +93,11 @@ from repro.kernels.matmul.matmul import (
     matmul_unicast,
 )
 from repro.kernels.paged_attention.paged_attention import (
+    page_write,
     paged_attention_decode,
     paged_attention_prefill,
 )
-from repro.kernels.paged_attention.ref import paged_attention_ref
+from repro.kernels.paged_attention.ref import paged_attention_ref, write_rows
 from repro.kernels.rglru.ref import rglru_scan_ref
 from repro.kernels.rglru.rglru import rglru_scan, rglru_scan_bwd
 from repro.kernels.ssd.ref import ssd_scan_ref
@@ -957,8 +960,8 @@ register(KernelOp(
 # ---------------------------------------------------------------------------
 
 
-def _paged_pallas(q, k_pages, v_pages, block_table, start, lengths, *scales,
-                  cfg, opts, interpret):
+def _paged_pallas(q, k_pages, v_pages, block_table, start, lengths, layer,
+                  *scales, cfg, opts, interpret):
     if q.shape[1] != 1 or scales:
         # only a by-name forced policy can land here: availability routes
         # multi-token / int8 problems to the supertile schedule
@@ -969,29 +972,38 @@ def _paged_pallas(q, k_pages, v_pages, block_table, start, lengths, *scales,
             "it automatically)"
         )
     o = paged_attention_decode(
-        q[:, 0], k_pages, v_pages, block_table, start, lengths,
+        q[:, 0], k_pages, v_pages, block_table, start, lengths, layer,
         softcap=opts["softcap"], interpret=interpret,
     )
     return o[:, None]
 
 
 def _paged_prefill_pallas(q, k_pages, v_pages, block_table, start, lengths,
-                          *scales, cfg, opts, interpret):
+                          layer, *scales, cfg, opts, interpret):
     k_scale, v_scale = scales if scales else (None, None)
     return paged_attention_prefill(
-        q, k_pages, v_pages, block_table, start, lengths,
+        q, k_pages, v_pages, block_table, start, lengths, layer,
         k_scale=k_scale, v_scale=v_scale, softcap=opts["softcap"],
         qc=cfg.get("qc"), interpret=interpret,
     )
 
 
-def _paged_reference(q, k_pages, v_pages, block_table, start, lengths, *scales,
-                     cfg, opts, interpret):
+def _paged_reference(q, k_pages, v_pages, block_table, start, lengths, layer,
+                     *scales, cfg, opts, interpret):
     k_scale, v_scale = scales if scales else (None, None)
     return paged_attention_ref(
-        q, k_pages, v_pages, block_table, start, lengths,
+        q, k_pages, v_pages, block_table, start, lengths, layer,
         softcap=opts["softcap"], k_scale=k_scale, v_scale=v_scale,
     )
+
+
+def _paged_problem(q, kp, vp, bt, st, ln, layer, *scales):
+    # (b, s, h, kv heads, pages_per_seq, page_size, head_dim, number of
+    # scale arrays) — lane groups count as the kv heads they pack, so
+    # packed and unpacked pools of one model are one problem
+    kvh = kp.shape[1] * (kp.shape[-1] // q.shape[3])
+    return (q.shape[0], q.shape[1], q.shape[2], kvh, bt.shape[1],
+            kp.shape[-2], q.shape[3], len(scales))
 
 
 _paged_fits = _fits_vmem("paged_attention")
@@ -999,14 +1011,11 @@ _paged_prefill_fits = _fits_vmem("paged_attention", "prefill")
 
 register(KernelOp(
     name="paged_attention",
-    # q: (b, s, h, d); pages: (kvh, P, ps, d); table: (b, pages_per_seq);
-    # start/lengths: (b,).  Trailing flag: number of scale arrays (int8
-    # pools pass 2 — the availability predicates read it, since opts
-    # can't see arity)
-    problem=lambda q, kp, vp, bt, st, ln, *scales: (
-        q.shape[0], q.shape[1], q.shape[2], kp.shape[0],
-        bt.shape[1], kp.shape[2], q.shape[3], len(scales),
-    ),
+    # q: (b, s, h, d); pools: the stack (L, G, P, ps, W); table:
+    # (b, pages_per_seq); start/lengths: (b,); the layer index; int8
+    # pools add their two scale arrays (the availability predicates
+    # read their count from the problem, since opts can't see arity)
+    problem=_paged_problem,
     opt_defaults=(("softcap", None),),
     schedules=(
         # single-token bf16/fp32 decode kernel: the cheapest pick for
@@ -1024,6 +1033,35 @@ register(KernelOp(
                  cost=_model_cost("paged_attention", "prefill"),
                  autotune_schedule="prefill", vjp=False),
         Schedule("reference", "reference", _paged_reference, vjp=True),
+    ),
+))
+
+
+def _page_write_pallas(k_pages, v_pages, k_new, v_new, page_ids, rows, layer,
+                       *, cfg, opts, interpret):
+    return page_write(k_pages, v_pages, k_new, v_new, page_ids, rows, layer,
+                      interpret=interpret)
+
+
+def _page_write_reference(k_pages, v_pages, k_new, v_new, page_ids, rows,
+                          layer, *, cfg, opts, interpret):
+    return (write_rows(k_pages, k_new, page_ids, rows, layer),
+            write_rows(v_pages, v_new, page_ids, rows, layer))
+
+
+register(KernelOp(
+    name="page_write",
+    # pools: (L, G, P, ps, W) stacks; new rows (b, s, G, W); page ids
+    # and in-page rows (b, s); the layer index.  Returns both pools.
+    problem=lambda kp, vp, kn, vn, ids, rows, layer: (
+        kn.shape[0], kn.shape[1], kp.shape[1], kp.shape[-1], kp.shape[2],
+        kp.shape[3],
+    ),
+    schedules=(
+        # in place: one page block read-modify-written per (row, lane
+        # group, page touched) under input_output_aliases
+        Schedule("pallas", "pallas", _page_write_pallas, vjp=False),
+        Schedule("reference", "reference", _page_write_reference, vjp=True),
     ),
 ))
 

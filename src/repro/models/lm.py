@@ -298,17 +298,17 @@ def prefill_to_pages(dense_caches, paged_caches, block_table, length, *,
 
 def _scatter_dense_into_pages(dense_c, paged_c, table, length):
     """dense_c: stacked KvCache (repeats, 1, s_pad, kv, hd);
-    paged_c: stacked paged cache (repeats, kvh, P, ps, ...)."""
+    paged_c: stacked paged cache (repeats, G, P, ps, W)."""
     ps = paged_c.k_pages.shape[3]
-    k = dense_c.k[:, 0].transpose(0, 2, 1, 3)  # (repeats, kv, s_pad, hd)
-    v = dense_c.v[:, 0].transpose(0, 2, 1, 3)
-    s_pad = k.shape[2]
+    s_pad = dense_c.k.shape[2]
     pos = jnp.arange(s_pad)
     valid = pos < length
     pidx = jnp.clip(pos // ps, 0, table.shape[0] - 1)
     ids = jnp.where(valid, table[pidx], 0)  # null-page sink for padding
     rows = jnp.where(valid, pos % ps, 0)
     if isinstance(paged_c, kvquant.QuantPagedKvCache):
+        k = dense_c.k[:, 0].transpose(0, 2, 1, 3)  # (repeats, kv, s_pad, hd)
+        v = dense_c.v[:, 0].transpose(0, 2, 1, 3)
         kq, ks = kvquant.quantize_kv(k)
         vq, vs = kvquant.quantize_kv(v)
         return kvquant.QuantPagedKvCache(
@@ -317,14 +317,20 @@ def _scatter_dense_into_pages(dense_c, paged_c, table, length):
             k_scale=paged_c.k_scale.at[:, :, ids, rows].set(ks),
             v_scale=paged_c.v_scale.at[:, :, ids, rows].set(vs),
         )
-    return attn_mod.PagedKvCache(
-        k_pages=paged_c.k_pages.at[:, :, ids, rows].set(
-            k.astype(paged_c.k_pages.dtype)
-        ),
-        v_pages=paged_c.v_pages.at[:, :, ids, rows].set(
-            v.astype(paged_c.v_pages.dtype)
-        ),
-    )
+    # one in-place page write per layer: the prompt's rows as one batch
+    # row, whole pages but the last
+    groups, lanes = paged_c.k_pages.shape[1], paged_c.k_pages.shape[-1]
+
+    def write_layer(pools, layer_kv):
+        layer, k, v = layer_kv
+        new = [a.reshape(1, s_pad, groups, lanes) for a in (k, v)]
+        return kernels.op("page_write")(
+            *pools, *new, ids[None], rows[None], layer), None
+
+    pools, _ = jax.lax.scan(
+        write_layer, tuple(paged_c),
+        (jnp.arange(dense_c.k.shape[0]), dense_c.k[:, 0], dense_c.v[:, 0]))
+    return attn_mod.PagedKvCache(*pools)
 
 
 # ---------------------------------------------------------------------------
@@ -334,8 +340,9 @@ def _scatter_dense_into_pages(dense_c, paged_c, table, length):
 
 def _apply_block(params, bd: BlockDef, cfg: ModelConfig, x, *, mode: str,
                  cache=None, index=None, cache_slots=None,
-                 block_table=None, lengths=None, page_axis=None):
-    """Returns (x, new_cache, aux_loss)."""
+                 block_table=None, lengths=None, page_axis=None, layer=None):
+    """Returns (x, new_cache, aux_loss).  ``layer``: a paged ``cache``
+    is the stack of every layer's pool and this block's index in it."""
     aux = jnp.zeros((), jnp.float32)
     h = _norm(cfg, params["norm1"], x)
     new_cache = cache
@@ -349,8 +356,8 @@ def _apply_block(params, bd: BlockDef, cfg: ModelConfig, x, *, mode: str,
                 )
                 m, new_cache = paged_fn(
                     params["attn"], h, cache, cfg.attn, index=index,
-                    block_table=block_table, lengths=lengths, window=bd.window,
-                    page_axis=page_axis,
+                    block_table=block_table, lengths=lengths, layer=layer,
+                    window=bd.window, page_axis=page_axis,
                 )
             else:
                 decode_fn = (
@@ -431,27 +438,50 @@ def _kv_from_full(params, h, cfg: ModelConfig, bd: BlockDef, cache_slots=None):
 def _run_stage(params_stage, pattern, cfg: ModelConfig, x, *, mode, caches=None,
                index=None, remat=False, cache_slots=None,
                block_table=None, lengths=None, page_axis=None):
+    # In decode, the K/V page pools ride the scan's carry whole, with the
+    # layer counter: each layer writes its rows in place and its kernel
+    # reads its pages from the stack, so no step slices out a layer's
+    # pool or builds a new stack from the slices.  Every other cache
+    # (dense, recurrent, int8 pools) goes through as scan inputs and
+    # outputs, one layer's slice per step.
+    pools = {}
+    if mode == "decode" and caches is not None:
+        pools = {k: c for k, c in caches.items()
+                 if isinstance(c, attn_mod.PagedKvCache)}
+        caches = {k: c for k, c in caches.items() if k not in pools} or None
+
     def super_block(carry, xs):
-        x, aux = carry
+        x, aux, pools, layer = carry
         p_sb, cache_sb = xs
+        pools = dict(pools)
         new_caches = {}
         for j, bd in enumerate(pattern):
-            c = cache_sb.get(f"b{j}") if cache_sb is not None else None
+            key = f"b{j}"
+            if key in pools:
+                c, at = pools[key], layer
+            else:
+                c = cache_sb.get(key) if cache_sb is not None else None
+                at = None
             x, nc, a = _apply_block(
-                p_sb[f"b{j}"], bd, cfg, x, mode=mode, cache=c, index=index,
+                p_sb[key], bd, cfg, x, mode=mode, cache=c, index=index,
                 cache_slots=cache_slots, block_table=block_table,
-                lengths=lengths, page_axis=page_axis,
+                lengths=lengths, page_axis=page_axis, layer=at,
             )
-            if nc is not None:
-                new_caches[f"b{j}"] = nc
+            if key in pools:
+                pools[key] = nc
+            elif nc is not None:
+                new_caches[key] = nc
             aux = aux + a
-        return (x, aux), (new_caches or None)
+        return (x, aux, pools, layer + 1), (new_caches or None)
 
     if remat:
         super_block = jax.checkpoint(super_block)
 
-    xs = (params_stage, caches)
-    (x, aux), new_caches = jax.lax.scan(super_block, (x, jnp.zeros((), jnp.float32)), xs)
+    carry = (x, jnp.zeros((), jnp.float32), pools, jnp.zeros((), jnp.int32))
+    (x, aux, pools, _), new_caches = jax.lax.scan(
+        super_block, carry, (params_stage, caches))
+    if pools:
+        new_caches = {**(new_caches or {}), **pools}
     return x, aux, new_caches
 
 

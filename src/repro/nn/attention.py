@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro import kernels
 from repro.configs.base import AttnConfig
+from repro.kernels.paged_attention import write_rows
 from repro.nn.memeff import memeff_attention
 from repro.nn.module import rope, softcap
 from repro.nn.spec import ParamSpec
@@ -79,6 +80,27 @@ def init_cache(batch: int, slots: int, cfg: AttnConfig, dtype=jnp.bfloat16):
     )
 
 
+LANES = 128  # the TPU's vector row
+
+
+def packed_heads(n_kv_heads: int, head_dim: int) -> int:
+    """How many kv heads share one 128-lane page row.
+
+    A (page_size, head_dim) page block narrower than a lane row would be
+    padded to 128 lanes, so XLA lays such a pool out page-minor and
+    every kernel read relays it.  Where ``128 // head_dim`` heads fill a
+    row exactly (``128 % head_dim == 0`` and ``n_kv_heads * head_dim %
+    128 == 0``, as for head_dim 64) they sit side by side and the pool
+    is ``(n_kv_heads * head_dim / 128, P, ps, 128)``.  The choice
+    depends on the head width alone: head_dim 128 already fills a row,
+    and other widths (80, 96, ...) keep one head per row and their
+    relayouts."""
+    if (head_dim < LANES and LANES % head_dim == 0
+            and n_kv_heads * head_dim % LANES == 0):
+        return LANES // head_dim
+    return 1
+
+
 class PagedKvCache(NamedTuple):
     """Page-pool KV cache: physical pages shared across sequences.
 
@@ -88,27 +110,34 @@ class PagedKvCache(NamedTuple):
     they are host-managed (``repro.serve``) and passed alongside, shared
     by every layer (one allocation covers the whole stack).  Pages
     referenced by several block tables (shared prefixes) exist once —
-    the serving-side multicast."""
+    the serving-side multicast.  Each row holds ``packed_heads`` kv
+    heads side by side (kv head ``g * pack + i`` in lanes ``[i * hd,
+    (i + 1) * hd)`` of group ``g``)."""
 
-    k_pages: jax.Array  # (kv_heads, num_pages, page_size, head_dim)
+    k_pages: jax.Array  # (groups, num_pages, page_size, pack * head_dim)
     v_pages: jax.Array
+
+
+def _pool_shape(num_pages: int, page_size: int, cfg: AttnConfig):
+    pack = packed_heads(cfg.n_kv_heads, cfg.head_dim)
+    return (cfg.n_kv_heads // pack, num_pages, page_size, pack * cfg.head_dim)
 
 
 def paged_cache_spec(num_pages: int, page_size: int, cfg: AttnConfig,
                      dtype=jnp.bfloat16):
-    kv, hd = cfg.n_kv_heads, cfg.head_dim
+    shape = _pool_shape(num_pages, page_size, cfg)
     return PagedKvCache(
-        k_pages=jax.ShapeDtypeStruct((kv, num_pages, page_size, hd), dtype),
-        v_pages=jax.ShapeDtypeStruct((kv, num_pages, page_size, hd), dtype),
+        k_pages=jax.ShapeDtypeStruct(shape, dtype),
+        v_pages=jax.ShapeDtypeStruct(shape, dtype),
     )
 
 
 def init_paged_cache(num_pages: int, page_size: int, cfg: AttnConfig,
                      dtype=jnp.bfloat16):
-    kv, hd = cfg.n_kv_heads, cfg.head_dim
+    shape = _pool_shape(num_pages, page_size, cfg)
     return PagedKvCache(
-        k_pages=jnp.zeros((kv, num_pages, page_size, hd), dtype),
-        v_pages=jnp.zeros((kv, num_pages, page_size, hd), dtype),
+        k_pages=jnp.zeros(shape, dtype),
+        v_pages=jnp.zeros(shape, dtype),
     )
 
 
@@ -134,12 +163,6 @@ def paged_positions(x, index, lengths, page_size: int, n_entries: int):
     return positions, page_slot, row, valid
 
 
-def paged_write(pages, values, page_ids, rows):
-    """Scatter new K/V rows into their pages: ``pages`` (kvh, P, ps, d),
-    ``values`` (b, s, kvh, d), ``page_ids``/``rows`` (b, s)."""
-    return pages.at[:, page_ids, rows].set(values.transpose(2, 0, 1, 3))
-
-
 def local_page_ids(ids, page_axis: str, block: int):
     """Global page ids -> this device's block of a page pool sharded
     evenly over the mesh axis ``page_axis`` (call inside a ``shard_map``
@@ -151,12 +174,20 @@ def local_page_ids(ids, page_axis: str, block: int):
 
 
 def write_and_attend(q, pages: tuple, new: tuple, block_table, page_ids, rows,
-                     start, lengths, *, softcap=None, page_axis: str | None = None):
-    """Scatter this call's new rows into the pool arrays, then run the
-    ``paged_attention`` op over them.  ``pages`` is the pool's array
-    tuple (K, V and, for int8 pools, the K/V scales, in that order) and
-    ``new`` the matching per-token rows (b, s, kvh, ·); ``page_ids`` /
-    ``rows`` are the write coordinates.  Returns ``(o, pages)``.
+                     start, lengths, layer, *, softcap=None,
+                     page_axis: str | None = None):
+    """Store this call's new rows in layer ``layer`` of the stacked pool,
+    then run the ``paged_attention`` op over it.  ``pages`` is the
+    pool's array tuple, each ``(L, G, P, ps, W)`` (K, V and, for int8
+    pools, the K/V scales, in that order) and ``new`` the matching rows
+    (b, s, G, W); ``page_ids`` / ``rows`` are the write coordinates.
+    Returns ``(o, pages)``.
+
+    K/V pools are written in place by the ``page_write`` op (an aliased
+    Pallas page write on TPU), so a step never copies, slices or relays
+    the pool.  int8 pools keep XLA's scatter: they are one layer's
+    pools, stacked as one, taken through the layer scan as scan inputs
+    and outputs (``models.lm``).
 
     ``page_axis`` names the mesh axis a sharded pool's page axis is
     split over; the caller runs this inside a ``shard_map`` over it, so
@@ -167,19 +198,23 @@ def write_and_attend(q, pages: tuple, new: tuple, block_table, page_ids, rows,
     length 0 and write to the null page), and a ``psum`` over the axis
     assembles the rows.  No page array leaves its device."""
     if page_axis is not None:
-        block = pages[0].shape[1]
+        block = pages[0].shape[2]
         block_table, mine = local_page_ids(block_table, page_axis, block)
         page_ids = local_page_ids(page_ids, page_axis, block)[0]
         owned = mine[:, 0]
         lengths = jnp.where(owned, lengths, 0)
-    pages = tuple(paged_write(a, n, page_ids, rows) for a, n in zip(pages, new))
+    if len(pages) == 2:
+        pages = kernels.op("page_write")(*pages, *new, page_ids, rows, layer)
+    else:
+        pages = tuple(write_rows(a, n, page_ids, rows, layer)
+                      for a, n in zip(pages, new))
     k, v, *scales = pages
     o = kernels.op("paged_attention")(
-        q, k, v, block_table, start, lengths, *scales, softcap=softcap)
+        q, k, v, block_table, start, lengths, layer, *scales, softcap=softcap)
     if page_axis is not None:
         o = jnp.where(owned[:, None, None, None], o, jnp.zeros_like(o))
         o = jax.lax.psum(o, page_axis)
-    return o, pages
+    return o, tuple(pages)
 
 
 def paged_decode_attention(
@@ -191,30 +226,47 @@ def paged_decode_attention(
     index: jax.Array,
     block_table: jax.Array,  # (b, pages_per_seq) int32
     lengths: jax.Array,  # (b,) int32 — valid tokens AFTER this call's writes
+    layer: jax.Array | None = None,
     window: int | None = None,
     page_axis: str | None = None,
 ):
     """Decode (or prefix-hit suffix prefill) against the page pool.
 
     ``x``: (b, s_new, d_model); ``index`` is the absolute position of
-    the first new token (scalar or (b,)).  The ``s_new`` new tokens are
-    written into their block-table pages first, then attention runs over
-    all ``lengths`` valid positions through the ``paged_attention``
-    kernel op: on TPU, single-token calls dispatch to the pallas decode
-    gather kernel and multi-token suffix prefills to the chunked-prefill
-    supertile kernel (one K/V page fetch multicast across the q chunk);
-    off-TPU both run the reference gather.  Calling this per suffix
-    *chunk* (increasing ``index``/``lengths``) leaves page bytes
-    identical to one call — the engine's chunked prefill relies on it.
-    ``page_axis``: the pool is sharded over that mesh axis and this runs
-    inside a ``shard_map`` over it (see :func:`write_and_attend`).
+    the first new token (scalar or (b,)).  ``cache`` is the stacked pool
+    of every layer, each array ``(L, G, P, ps, W)``, and ``layer`` this
+    layer's index in it; without ``layer`` it is one layer's pool.  The
+    ``s_new`` new tokens are written into their block-table pages
+    first, in place, then attention runs over all ``lengths`` valid
+    positions through the ``paged_attention`` kernel op: on TPU,
+    single-token calls dispatch to the pallas decode gather kernel and
+    multi-token suffix prefills to the chunked-prefill supertile kernel
+    (one K/V page fetch multicast across the q chunk); off-TPU both run
+    the reference gather.  Calling this per suffix *chunk* (increasing
+    ``index``/``lengths``) leaves page bytes identical to one call — the
+    engine's chunked prefill relies on it.  ``page_axis``: the pool is
+    sharded over that mesh axis and this runs inside a ``shard_map``
+    over it (see :func:`write_and_attend`).
     """
+    return _paged_call(params, x, cache, cfg, index=index,
+                       block_table=block_table, lengths=lengths, layer=layer,
+                       window=window, page_axis=page_axis, quantize=None)
+
+
+def _paged_call(params, x, cache, cfg: AttnConfig, *, index, block_table,
+                lengths, layer, window, page_axis, quantize):
+    """The paged decode attention of both pool kinds: ``quantize`` maps
+    new (b, s, kvh, hd) K or V rows to the arrays the pool stores for
+    them (None: the rows themselves, in the pool's dtype)."""
     if window is not None:
         raise NotImplementedError(
             "paged KV serving covers global attention only; local-window "
             "blocks use the dense ring-buffer path"
         )
-    ps = cache.k_pages.shape[2]
+    one_layer = layer is None
+    pages = tuple(a[None] for a in cache) if one_layer else tuple(cache)
+    layer = jnp.int32(0) if one_layer else layer
+    ps = pages[0].shape[-2]
     lengths = jnp.asarray(lengths, jnp.int32)
     positions, page_slot, rows, valid = paged_positions(
         x, index, lengths, ps, block_table.shape[1]
@@ -223,13 +275,22 @@ def paged_decode_attention(
     page_ids = jnp.where(
         valid, jnp.take_along_axis(block_table, page_slot, axis=1), 0
     )
-    dtype = cache.k_pages.dtype
+    b, s = page_ids.shape
+    if quantize is None:
+        new = (k_new, v_new)
+    else:
+        (kq, ks), (vq, vs) = quantize(k_new), quantize(v_new)
+        new = (kq, vq, ks, vs)
+    # (b, s, kvh, hd) -> (b, s, G, W): packed heads share a lane row
+    new = tuple(n.reshape(b, s, *a.shape[1:2], a.shape[-1]).astype(a.dtype)
+                for n, a in zip(new, pages))
     o, pages = write_and_attend(
-        q, tuple(cache), (k_new.astype(dtype), v_new.astype(dtype)),
-        block_table, page_ids, rows, positions[:, 0], lengths,
-        softcap=cfg.logit_softcap, page_axis=page_axis,
+        q, pages, new, block_table, page_ids, rows, positions[:, 0], lengths,
+        layer, softcap=cfg.logit_softcap, page_axis=page_axis,
     )
-    return _proj_out(params, o, cfg), PagedKvCache(*pages)
+    if one_layer:
+        pages = tuple(a[0] for a in pages)
+    return _proj_out(params, o, cfg), type(cache)(*pages)
 
 
 def _qkv(params, x, cfg: AttnConfig, positions):

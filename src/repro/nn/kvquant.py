@@ -20,10 +20,9 @@ from repro.configs.base import AttnConfig
 from repro.nn.attention import (
     KvCache,
     _attend,
+    _paged_call,
     _proj_out,
     _qkv,
-    paged_positions,
-    write_and_attend,
 )
 
 
@@ -150,6 +149,7 @@ def quant_paged_decode_attention(
     index: jax.Array,
     block_table: jax.Array,
     lengths: jax.Array,
+    layer: jax.Array | None = None,
     window: int | None = None,
     page_axis: str | None = None,
 ):
@@ -157,28 +157,10 @@ def quant_paged_decode_attention(
     rows are quantised on the way in, the attention gather dequantises
     on the way out (fused in-kernel on the pallas supertile schedule,
     on the gathered copy in the reference backend)."""
-    if window is not None:
-        raise NotImplementedError(
-            "paged KV serving covers global attention only; local-window "
-            "blocks use the dense ring-buffer path"
-        )
-    ps = cache.k_pages.shape[2]
-    lengths = jnp.asarray(lengths, jnp.int32)
-    positions, page_slot, rows, valid = paged_positions(
-        x, index, lengths, ps, block_table.shape[1]
-    )
-    q, k_new, v_new = _qkv(params, x, cfg, positions)
-    page_ids = jnp.where(
-        valid, jnp.take_along_axis(block_table, page_slot, axis=1), 0
-    )
-    kq_new, ks_new = quantize_kv(k_new)
-    vq_new, vs_new = quantize_kv(v_new)
-    o, pages = write_and_attend(
-        q, tuple(cache), (kq_new, vq_new, ks_new, vs_new),
-        block_table, page_ids, rows, positions[:, 0], lengths,
-        softcap=cfg.logit_softcap, page_axis=page_axis,
-    )
-    return _proj_out(params, o, cfg), QuantPagedKvCache(*pages)
+    return _paged_call(params, x, cache, cfg, index=index,
+                       block_table=block_table, lengths=lengths, layer=layer,
+                       window=window, page_axis=page_axis,
+                       quantize=quantize_kv)
 
 
 def cache_bytes(cache) -> int:

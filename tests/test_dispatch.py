@@ -426,12 +426,14 @@ def _fake_op():
 def test_resolve_reports_vjp_capability():
     res = kernels.resolve("matmul", (256, 128, 128), jnp.float32, policy="tiled")
     assert res.vjp is True and res.schedule == "tiled"
-    # every registered training-path schedule carries a VJP; the one
-    # deliberate exception is the paged_attention decode kernel, which
-    # is serving-only (nothing differentiates through a decode step)
+    # every registered training-path schedule carries a VJP; the
+    # deliberate exceptions are the paged_attention kernels and the
+    # in-place page write, which are serving-only (nothing
+    # differentiates through a decode step)
     for op_name in kernels.ops():
         for sched in api.op(op_name).schedules:
-            if op_name == "paged_attention" and sched.backend == "pallas":
+            if op_name in ("paged_attention", "page_write") \
+                    and sched.backend == "pallas":
                 assert not sched.vjp, (op_name, sched.name)
                 continue
             assert sched.vjp, (op_name, sched.name)

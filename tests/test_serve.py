@@ -104,6 +104,47 @@ def test_paged_matches_dense_with_shared_prefix(small):
     eng.check()
 
 
+@pytest.mark.parametrize("pressure", [False, True],
+                         ids=["shared_prefix", "preempting"])
+def test_packed_heads_serve_like_one_head_per_row(small, monkeypatch,
+                                                  pressure):
+    """head_dim 64 with 2 kv heads: the pool stores both heads side by
+    side in each 128-lane row.  Prefix sharing with the COW of a shared
+    partial page, and preemption's swap round trip under a pool too
+    small for two requests, serve the same tokens from it as from a
+    pool with one head per row."""
+    import dataclasses
+
+    from repro.configs.base import AttnConfig
+    from repro.nn import attention
+
+    cfg = dataclasses.replace(small[0], attn=AttnConfig(
+        n_heads=4, n_kv_heads=2, head_dim=64, qkv_bias=True))
+    params = lm.init(cfg, KEY)
+    reqs = _mk_requests(cfg, n=3, max_new=10, seed=3) if pressure \
+        else _mk_requests(cfg, shared_prefix=20, n=4)
+    kw = dict(page_size=4, num_pages=7, watermark=1) if pressure \
+        else dict(page_size=8)
+
+    def serve():
+        eng = PagedEngine(cfg, params, max_batch=2, cache_len=64, **kw)
+        out = {r.rid: r.out for r in eng.run(_clone(reqs))}
+        eng.check()
+        return eng, out
+
+    eng, packed = serve()
+    assert eng.caches["stage0"]["b0"].k_pages.shape[1:] == (
+        1, eng.pool.num_pages, kw["page_size"], 128)
+    if pressure:
+        assert eng.n_preempted > 0
+    else:
+        assert eng.stats()["prefix_hit_tokens"] >= 3 * 16
+    monkeypatch.setattr(attention, "packed_heads", lambda kvh, hd: 1)
+    eng, unpacked = serve()
+    assert eng.caches["stage0"]["b0"].k_pages.shape[1] == 2
+    assert packed == unpacked
+
+
 def test_prefix_pages_allocated_exactly_once(small):
     cfg, params = small
     n, prefix_len, ps = 4, 32, 8

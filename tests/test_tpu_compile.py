@@ -1,7 +1,8 @@
 """Compile the main serving path for a TPU v5e that is described, not
 attached: the Pallas kernels at qwen1.5-0.5b widths, one whole
-full-width paged decode step, and the 4-device decode step over the
-mesh-sharded page pool.  Mosaic refuses here what interpret mode
+full-width paged decode step, the model steps at both benchmark cells'
+shapes (checked to leave the page pool in place), and the 4-device
+decode step over the mesh-sharded page pool.  Mosaic refuses here what interpret mode
 accepts (misaligned tiles, VMEM overruns, kernels GSPMD cannot
 partition).  Nothing runs: these are compiles only.
 
@@ -77,23 +78,31 @@ def _custom_calls(compiled) -> int:
 def _named(compiled, name: str) -> list[str]:
     """The instructions named after kernel ``name`` (``%name.1 = ...``):
     the name the profiler's trace shows for the kernel's operation."""
-    return re.findall(rf"%{name}(?:\.\d+)? = \S+ custom-call\(",
+    return re.findall(rf"%{name}(?:\.\d+)? = [^=]*? custom-call\(",
                       compiled.as_text())
 
 
 def _paged_args(cfg, b, s, num_pages, sharding, *, int8=False):
+    """A kernel call as the decode step makes it: the stack of every
+    layer's pool (bf16 lane-packed; int8 one head per row, a stack of
+    the one layer the scan hands it), then the layer index."""
     kvh, h, d = cfg.attn.n_kv_heads, cfg.attn.n_heads, cfg.attn.head_dim
-    pdt = jnp.int8 if int8 else jnp.bfloat16
+    if int8:
+        pool = jax.ShapeDtypeStruct((1, kvh, num_pages, PAGE, d), jnp.int8)
+    else:
+        pool = jax.eval_shape(lambda: lm.init_paged_cache(
+            cfg, num_pages, PAGE))["stage0"]["b0"].k_pages
     args = [
         _sds((b, s, h, d), jnp.bfloat16, sharding),
-        _sds((kvh, num_pages, PAGE, d), pdt, sharding),
-        _sds((kvh, num_pages, PAGE, d), pdt, sharding),
+        _sds(pool.shape, pool.dtype, sharding),
+        _sds(pool.shape, pool.dtype, sharding),
         _sds((b, WIDTH), jnp.int32, sharding),
         _sds((b,), jnp.int32, sharding),
         _sds((b,), jnp.int32, sharding),
+        _sds((), jnp.int32, sharding),  # the layer
     ]
     if int8:
-        args += [_sds((kvh, num_pages, PAGE, 1), jnp.bfloat16, sharding)] * 2
+        args += [_sds((1, kvh, num_pages, PAGE, 1), jnp.bfloat16, sharding)] * 2
     return args
 
 
@@ -153,13 +162,110 @@ def test_full_width_decode_step_compiles_and_fits(cfg, one_chip, on_tpu):
     args = _decode_args(cfg, 1025, 8, one_chip, one_chip, one_chip)
     compiled = jax.jit(_decode_step(cfg), donate_argnums=(1,)) \
         .lower(*args).compile()
-    # per layer: qkv (3) + out + MLP up/gate + down matmuls and the paged
-    # decode kernel, inside one scanned body; plus embed-tied logits
-    assert _custom_calls(compiled) == 9
+    # per layer: qkv (3) + out + MLP up/gate + down matmuls, the page
+    # write and the paged decode kernel, inside one scanned body; plus
+    # embed-tied logits
+    assert _custom_calls(compiled) == 10
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("s", [1, 5, 64, 512])
+def test_page_write_kernel_compiles_in_place(cfg, one_chip, on_tpu, s):
+    """The page write at a decode row (s 1), a speculative verify (5), a
+    suffix chunk (64) and a cold prompt (512): one kernel, and the pools
+    it returns are the buffers it was given."""
+    pool = jax.eval_shape(lambda: lm.init_paged_cache(
+        cfg, 1025, PAGE))["stage0"]["b0"].k_pages
+    g, w = pool.shape[1], pool.shape[-1]
+    b = 1 if s > 64 else 8
+    args = ([_sds(pool.shape, pool.dtype, one_chip)] * 2
+            + [_sds((b, s, g, w), jnp.bfloat16, one_chip)] * 2
+            + [_sds((b, s), jnp.int32, one_chip)] * 2
+            + [_sds((), jnp.int32, one_chip)])
+    f = jax.jit(lambda *a: kernels.op("page_write")(*a, policy="pallas"),
+                donate_argnums=(0, 1))
+    compiled = f.lower(*args).compile()
+    assert _custom_calls(compiled) == 1
+    assert len(_named(compiled, "page_write")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    assert not _pool_ops(compiled.as_text(), 1025)
+
+
+# the benchmark cells' model steps: (pool pages, decode batch)
+CELLS = {"qwen1.5-0.5b": (3073, 8), "qwen1.5-1.8b": (1793, 16)}
+POOL_OPS = ("copy", "dynamic-slice", "dynamic-update-slice", "scatter",
+            "transpose", "fusion")
+
+
+def _pool_ops(hlo: str, num_pages: int) -> list[str]:
+    """Instructions that copy, slice, relay or scatter a pool-sized
+    array (one layer's pool or the stack, by its page and row dims),
+    fusions included: the bytes of the pool a step moves besides its
+    kernels' reads and writes."""
+    shape = rf"\[[\d,]*\b{num_pages},{PAGE},\d+\]"
+    op = "|".join(re.escape(o) for o in POOL_OPS)
+    return [ln.strip()[:160] for ln in hlo.splitlines()
+            if re.search(rf"= \w+{shape}\S* ({op})\(", ln)]
+
+
+def _pool_layouts(hlo: str, num_pages: int) -> set[str]:
+    """The minor-to-major layouts of the entry's pool parameters."""
+    return set(re.findall(
+        rf"= \w+\[[\d,]*\b{num_pages},{PAGE},\d+\]\{{([\d,]+)[:}}][^=]*"
+        rf"parameter\(", hlo))
+
+
+def _cell_step(cfg, kind, b, s, num_pages, sharding):
+    """(jitted step, its argument shapes) of one of the engine's model
+    steps at a cell's shape, the pool donated as the engine does."""
+    params, caches, *_ = _decode_args(cfg, num_pages, b, sharding, sharding,
+                                      sharding)
+    if kind == "decode":
+        step = _decode_step(cfg)
+        rest = (_sds((b, s), jnp.int32, sharding),
+                _sds((b,), jnp.int32, sharding),
+                _sds((b, WIDTH), jnp.int32, sharding),
+                _sds((b,), jnp.int32, sharding))
+    else:
+        def step(p, c, toks, li, row, length):
+            logits, dense = lm.prefill(p, cfg, toks, logit_index=li)
+            return logits, lm.prefill_to_pages(dense, c, row, length)
+        rest = (_sds((1, s), jnp.int32, sharding),
+                _sds((), jnp.int32, sharding),
+                _sds((WIDTH,), jnp.int32, sharding),
+                _sds((), jnp.int32, sharding))
+    return jax.jit(step, donate_argnums=(1,)), (params, caches) + rest
+
+
+@pytest.mark.parametrize("kind,s", [("decode", 1), ("decode", 64),
+                                    ("cold_prefill", 512)])
+@pytest.mark.parametrize("arch", sorted(CELLS))
+def test_cell_step_leaves_the_pool_in_place(one_chip, on_tpu, arch, kind, s):
+    """Both cells' model steps (the decode tick, a 64-token suffix chunk
+    through the same decode step, and a cold 512-token prefill written
+    to pages) relay no pool bytes: no pool-sized copy, slice, scatter or
+    fusion, the pool kept in the row-major layout the kernels read
+    (lane-packed at head_dim 64), and the decode step's temporaries well
+    under one layer's pool."""
+    cfg = get_config(arch)
+    num_pages, b = CELLS[arch]
+    step, args = _cell_step(cfg, kind, 1 if kind != "decode" else b, s,
+                            num_pages, one_chip)
+    compiled = step.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert not _pool_ops(hlo, num_pages), _pool_ops(hlo, num_pages)[:4]
+    assert _pool_layouts(hlo, num_pages) == {"4,3,2,1,0"}
+    assert len(_named(compiled, "page_write")) == 1
+    if kind == "decode":
+        # less the f32 output head and logits that _logits builds each
+        # step (a separate item: PERF.md section 5), the step holds
+        # under 1 GiB of temporaries; the pool is 4.5-5.3 GiB
+        head = 4 * cfg.vocab * (cfg.d_model + b * s)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp - head < 2**30, (temp, head)
 
 
 def test_sharded_decode_step_compiles_without_page_gathers(cfg, topo, on_tpu):
@@ -183,4 +289,5 @@ def test_sharded_decode_step_compiles_without_page_gathers(cfg, topo, on_tpu):
     for line in hlo.splitlines():
         if re.search(r"all-reduce(-start)?\(|collective-permute", line):
             assert f",{PAGE},{d}]" not in line, line  # never a page array
+            assert f",{PAGE},128]" not in line, line
             assert f"{kvh},{d}]" in line or "[]" in line, line
